@@ -19,10 +19,14 @@ HBM budget would, and reports
 
 The model is the reduced glm4-9b with heads widened to 8/4 so tp=4
 genuinely splits (the stock reduced config has 2 kv heads and would fall
-back to replication).  Needs 8 visible devices: when the current process
-booted without ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` the
-benchmark re-execs itself in a subprocess with the flag set (jax fixes the
-device count at backend init, so an in-process retry can't work).
+back to replication).  The sweep runs on 8 forced CPU host devices, so it
+says nothing about a chip interconnect.  Unless this process was started
+pinned to them (``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``), the benchmark
+re-execs itself in a child so pinned — decided from the environment alone,
+so the parent never touches JAX and never holds a chip the child would
+need (jax fixes the platform and device count at backend init, so an
+in-process retry can't work).
 
 Emits ``name,us_per_call,derived`` CSV rows plus ``BENCH_tp.json`` (seed +
 git rev recorded).  ``--smoke`` keeps the same workload so baseline and CI
@@ -33,6 +37,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -45,17 +50,30 @@ NEEDED_DEVICES = 8
 _CHILD_ENV = "REPRO_BENCH_TP_CHILD"
 
 
+def _pinned_host_devices() -> int:
+    """Forced CPU host devices this process was started with (0 unless it
+    is pinned to the CPU) — read from the environment, not from JAX."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return 0
+    counts = re.findall(
+        r"--xla_force_host_platform_device_count=(\d+)",
+        os.environ.get("XLA_FLAGS", ""),
+    )
+    return int(counts[-1]) if counts else 0
+
+
 def _reexec_with_devices(smoke: bool, seed: int) -> dict:
-    """Re-run this benchmark in a subprocess with forced host devices."""
+    """Re-run this benchmark in a child pinned to forced CPU host devices."""
     if os.environ.get(_CHILD_ENV):
         raise RuntimeError(
             f"still only saw < {NEEDED_DEVICES} devices after forcing "
             f"host devices; is another XLA_FLAGS value overriding it?"
         )
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={NEEDED_DEVICES} "
-        + env.get("XLA_FLAGS", "")
+        env.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={NEEDED_DEVICES}"
     ).strip()
     env[_CHILD_ENV] = "1"
     env.setdefault("PYTHONPATH", "src")
@@ -68,16 +86,15 @@ def _reexec_with_devices(smoke: bool, seed: int) -> dict:
 
 
 def run(smoke: bool = False, seed: int = 0) -> dict:
-    import jax
-
-    if jax.device_count() < NEEDED_DEVICES:
+    if _pinned_host_devices() < NEEDED_DEVICES:
         return _reexec_with_devices(smoke, seed)
+    import jax
 
     from repro.configs import get_config
     from repro.core.analysis import percentile, tp_summary
     from repro.core.manifest import EngineKnobs
     from repro.core.tracing import Tracer, TracingServer
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_serve_mesh
     from repro.models import build_model
     from repro.serve.engine import ServeRequest, ServingEngine
     from repro.sharding.specs import serve_rules
@@ -103,7 +120,7 @@ def run(smoke: bool = False, seed: int = 0) -> dict:
     ]
 
     def serve(tp: int, tracer=None):
-        rules = serve_rules(make_host_mesh(tp=tp)) if tp > 1 else None
+        rules = serve_rules(make_serve_mesh(tp=tp)) if tp > 1 else None
         engine = ServingEngine(
             model, params, max_batch=num_slots, max_seq=max_seq,
             page_size=page_size, rules=rules,

@@ -7,6 +7,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import emit_header
 
 BENCHES = [
@@ -32,6 +34,7 @@ def main() -> None:
     ap.add_argument("--only", default="", help="comma-separated bench keys")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
     emit_header()
     failures = []
     for key, module in BENCHES:

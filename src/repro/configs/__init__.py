@@ -67,6 +67,29 @@ def get_config(arch: str, reduced: bool = False) -> ArchConfig:
     return mod.REDUCED if reduced else mod.CONFIG
 
 
+def depth_cut(cfg: ArchConfig, layers: int) -> ArchConfig:
+    """The first ``layers`` layers of ``cfg`` at unchanged widths: one
+    chip's share of a deployment whose remaining layers would sit on
+    further chips as pipeline stages.  ``layers <= 0`` (or at least the
+    published depth) keeps every layer.  The cut must keep whole periods of
+    the layer pattern (local/global alternation, dense/expert interleave,
+    shared-attention spacing) so every kind of layer stays in its ratio."""
+    if layers <= 0 or layers >= cfg.num_layers:
+        return cfg
+    period = max(
+        cfg.global_every,
+        cfg.moe_every if cfg.family == "moe" else 1,
+        cfg.hybrid_attn_every,
+        1,
+    )
+    if layers % period:
+        raise ValueError(
+            f"{cfg.name}: a {layers}-layer cut splits its {period}-layer "
+            f"pattern; pick a multiple of {period}"
+        )
+    return cfg.replace(name=f"{cfg.name}-{layers}L", num_layers=layers)
+
+
 def list_archs() -> List[str]:
     return list(_ALIASES)
 

@@ -78,6 +78,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    from ..launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     platform = LocalPlatform(
         backends=args.backends.split(","), evaldb_path=args.evaldb
     )

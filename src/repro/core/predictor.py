@@ -131,11 +131,11 @@ def available_backends() -> list:
 
 
 def _register_builtin() -> None:
-    """Register the JAX model-zoo predictors lazily (import cycle guard)."""
-    try:
-        from ..models.predictor import JaxModelPredictor  # noqa: WPS433
-    except Exception:  # pragma: no cover - models package optional at import
-        return
+    """Register the JAX model-zoo predictors (imported here, not at module
+    top, to break the models -> core import cycle).  An import failure
+    propagates: a platform without its backends is a broken install."""
+    from ..models.predictor import JaxModelPredictor  # noqa: WPS433
+
     for backend in ("ref", "pallas"):
         if backend not in _FACTORIES:
             register_predictor(

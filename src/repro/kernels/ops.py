@@ -9,6 +9,10 @@ Models call these ops with a ``backend`` string:
                representative of the TPU target).
 * ``pallas`` — the Pallas TPU kernels (``interpret=True`` on CPU for tests).
 
+``backend=None`` means :func:`default_backend`: the Pallas kernels on a
+TPU, ``flash`` elsewhere.  ``flash`` and ``ref`` run on a TPU only when a
+caller names them (as oracles).
+
 All ops are shape/dtype-polymorphic and jit-friendly.
 """
 from __future__ import annotations
@@ -21,8 +25,15 @@ import jax.numpy as jnp
 
 from . import ref
 
-DEFAULT_BACKEND = "flash"
 NEG_INF = ref.NEG_INF
+
+
+def default_backend() -> str:
+    """The kernels this platform runs: Pallas on a TPU; elsewhere the
+    chunked pure-JAX path (interpret-mode Pallas is a test oracle there,
+    not a runtime).  Asked at call time, so importing never touches the
+    device."""
+    return "pallas" if jax.default_backend() == "tpu" else "flash"
 
 
 def _soft_cap(x: jnp.ndarray, cap: float) -> jnp.ndarray:
@@ -48,14 +59,12 @@ def _shard_heads(body, mesh, axis, in_specs, out_specs):
     Every rank runs the identical attention program on its own head slice —
     attention never mixes heads, so per-shard outputs are bit-exact slices
     of the unsharded result and no collective is needed until the o-proj
-    contraction outside the kernel.  ``check_rep=False``: the replicated
+    contraction outside the kernel.  ``check_vma=False``: the replicated
     page tables/lengths feed gathers whose replication the checker can't
     prove."""
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -252,9 +261,10 @@ def attention(
     softcap: float = 0.0,
     q_offset: int = 0,
     scale: Optional[float] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     block_k: int = 512,
 ) -> jnp.ndarray:
+    backend = backend or default_backend()
     if backend == "ref":
         return ref.attention(
             q, k, v, causal=causal, window=window, softcap=softcap,
@@ -287,7 +297,7 @@ def decode_attention(
     softcap: float = 0.0,
     window=None,
     scale: Optional[float] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     kv_bound: Optional[int] = None,
 ) -> jnp.ndarray:
     """``kv_bound`` is a static host-known upper bound on ``lengths``: decode
@@ -295,6 +305,7 @@ def decode_attention(
     ``S`` padded blocks (serving buckets it to a power of two so short
     contexts stop paying the full-cache bandwidth tax).  Invalid for ring
     caches, whose live tokens wrap the whole buffer."""
+    backend = backend or default_backend()
     if backend == "pallas":
         from . import decode_attention as da
 
@@ -480,7 +491,7 @@ def varlen_prefill(
     softcap: float = 0.0,
     window=None,
     scale: Optional[float] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     pages_bound: Optional[int] = None,
     k_scales: Optional[jnp.ndarray] = None,
     v_scales: Optional[jnp.ndarray] = None,
@@ -491,6 +502,7 @@ def varlen_prefill(
     bounds context pages per chunk (host-known, bucketed)."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     quantized = k_scales is not None
+    backend = backend or default_backend()
 
     def body(q, k, v, k_pages, v_pages, cu_seqlens, chunk_lens, chunk_pos0,
              page_tables, *scales):
@@ -548,7 +560,7 @@ def paged_attention(
     softcap: float = 0.0,
     window=None,
     scale: Optional[float] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     pages_bound: Optional[int] = None,
     k_scales: Optional[jnp.ndarray] = None,
     v_scales: Optional[jnp.ndarray] = None,
@@ -561,6 +573,7 @@ def paged_attention(
         page_table = page_table[:, :pages_bound]
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     quantized = k_scales is not None
+    backend = backend or default_backend()
 
     def body(q, k_pages, v_pages, page_table, lengths, *scales):
         sc = dict(zip(("k_scales", "v_scales"), scales))
@@ -764,7 +777,7 @@ def spec_verify(
     softcap: float = 0.0,
     window=None,
     scale: Optional[float] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     pages_bound: Optional[int] = None,
     k_scales: Optional[jnp.ndarray] = None,
     v_scales: Optional[jnp.ndarray] = None,
@@ -779,6 +792,7 @@ def spec_verify(
         page_table = page_table[:, :pages_bound]
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     quantized = k_scales is not None
+    backend = backend or default_backend()
 
     def body(q, k_pages, v_pages, page_table, lengths, window_lens, *scales):
         sc = dict(zip(("k_scales", "v_scales"), scales))
@@ -823,13 +837,25 @@ def rmsnorm(
     weight: jnp.ndarray,
     eps: float = 1e-6,
     *,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jnp.ndarray:
-    if backend == "pallas":
-        from . import rmsnorm as rn
+    backend = backend or default_backend()
+    if backend != "pallas":
+        return ref.rmsnorm(x, weight, eps=eps)
+    from . import rmsnorm as rn
+    from ..sharding.specs import activation_rules
 
-        return rn.rmsnorm(x, weight, eps=eps)
-    return ref.rmsnorm(x, weight, eps=eps)
+    body = functools.partial(rn.rmsnorm, eps=eps)
+    rules = activation_rules()
+    if rules is None:
+        return body(x, weight)
+    # a Mosaic kernel cannot be partitioned by GSPMD: under sharding rules
+    # every device normalizes the whole (replicated) activation itself
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(
+        body, mesh=rules.mesh, in_specs=(P(), P()), out_specs=P(),
+        check_vma=False,
+    )(x, weight)
 
 
 # ---------------------------------------------------------------------------
@@ -909,8 +935,9 @@ def ssd(
     chunk: int = 64,
     initial_state=None,
     return_state: bool = False,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ):
+    backend = backend or default_backend()
     if backend == "ref":
         return ref.ssd(x, dt, A, B, C, initial_state=initial_state, return_state=return_state)
     if backend == "flash":
@@ -928,6 +955,6 @@ def ssd(
     raise ValueError(f"unknown ssd backend {backend!r}")
 
 
-def ssd_step(x, dt, A, B, C, state, *, backend: str = DEFAULT_BACKEND):
+def ssd_step(x, dt, A, B, C, state, *, backend: Optional[str] = None):
     """Decode step — shared implementation (already O(1) in seq)."""
     return ref.ssd_step(x, dt, A, B, C, state)

@@ -2,11 +2,20 @@
 
 The KV cache is a global pool of ``page_size``-token pages shared by every
 request; each request owns a list of pages recorded in a per-request page
-table.  Grid = (batch, q_heads, kv_pages) with the page dimension innermost
-and sequential so the flash-decode online-softmax state lives in VMEM
-scratch.  The page table and per-request ``lengths`` arrive as
-scalar-prefetch operands: the k/v BlockSpec index maps dereference the page
-table so only a request's *live* pages stream HBM->VMEM — pages beyond
+table.  Grid = (batch, kv_pages) with the page dimension innermost and
+sequential so the flash-decode online-softmax state lives in VMEM scratch.
+Each program streams one page with EVERY kv head in it, ``(page_size, kvh,
+d)``, and scores it against every query head of the request: the query
+arrives grouped as ``(kvh, rep, d)`` and a static loop over the kv heads
+runs one ``(rep, d) x (d, page_size)`` matmul per group, so each page is
+read once per request, not once per query head.  Both block shapes end in
+whole array dims (``(kvh, d)`` and ``(rep, d)``), which is what the TPU
+compiler requires of a block whose last two dims are not multiples of
+``(8, 128)``.
+
+The page table and per-request ``lengths`` arrive as scalar-prefetch
+operands: the k/v BlockSpec index maps dereference the page table so only a
+request's *live* pages stream HBM->VMEM — pages beyond
 ``ceil(len/page_size)`` are clamped to the request's last live page, which
 Pallas recognises as a revisit (no new DMA).  The caller additionally bounds
 the grid with ``pages_bound`` (host-known max live pages, bucketed), so the
@@ -17,8 +26,7 @@ and a parallel ``(num_pages, page_size, kvh)`` float32 scale pool carries
 one scale per row per kv head.  The scale blocks stream through the same
 page-table index map as their K/V pages and dequantization (``q * scale``)
 is fused right after the block load — quantized K/V never materializes in
-full precision outside the kernel.  With scales absent the trace is
-bit-identical to the unquantized kernel.
+full precision outside the kernel.
 """
 from __future__ import annotations
 
@@ -30,10 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; bridge both
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover - version compat
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -41,9 +45,9 @@ def _kernel(
     pt_ref,                    # scalar prefetch: (b, max_pages) int32 page table
     lens_ref,                  # scalar prefetch: (b,) int32 valid lengths
     w_ref,                     # scalar prefetch: (1,) int32 window (0 = none)
-    q_ref,                     # (1, 1, 1, d)
-    k_ref, v_ref,              # (1, page_size, 1, d) — one page
-    *rest,                     # [ks_ref, vs_ref (1, page_size, 1)], o_ref, scratch
+    q_ref,                     # (1, kvh, rep, d)
+    k_ref, v_ref,              # (1, page_size, kvh, d) — one page, every kv head
+    *rest,                     # [ks_ref, vs_ref (1, page_size, kvh)], o_ref, scratch
     softcap: float,
     page_size: int,
     scale: float,
@@ -54,8 +58,9 @@ def _kernel(
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     bi = pl.program_id(0)
-    pj = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    pj = pl.program_id(1)
+    np_ = pl.num_programs(1)
+    _, kvh, rep, d = q_ref.shape
 
     @pl.when(pj == 0)
     def _init():
@@ -63,43 +68,51 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0, 0, :]                                   # (d,)
-    k = k_ref[0, :, 0, :]                                   # (page_size, d)
-    v = v_ref[0, :, 0, :]
-    if quantized:
-        # fused dequant: one f32 scale per page row for this kv head
-        k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
     length = lens_ref[bi]
+    w = w_ref[0]
     # positions are *logical*: page pj of this request covers
     # [pj*page_size, (pj+1)*page_size) regardless of which physical page
     # the index map streamed in
-    k_pos = pj * page_size + jax.lax.iota(jnp.int32, page_size)
-    valid = k_pos < length
-    w = w_ref[0]
-    valid &= jnp.where(w > 0, k_pos >= length - w, True)
-    v = jnp.where(valid[:, None], v, 0.0)
-    s = jnp.sum(
-        q[None, :].astype(jnp.float32) * k.astype(jnp.float32), axis=-1
-    ) * scale                                               # (page_size,)
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[0] = l_ref[0] * alpha + jnp.sum(p)
-    m_ref[0] = m_new
-    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(
-        p[:, None].astype(jnp.float32) * v.astype(jnp.float32), axis=0
-    )[None]
+    k_pos = pj * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (rep, page_size), 1
+    )
+    # (w <= 0) | ...: Mosaic cannot select between boolean vectors
+    valid = (k_pos < length) & ((w <= 0) | (k_pos >= length - w))
+    row_pos = pj * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, d), 0
+    )
+    row_valid = (row_pos < length) & ((w <= 0) | (row_pos >= length - w))
+    for g in range(kvh):                      # static: one MXU pass per group
+        q = q_ref[0, g]                                     # (rep, d)
+        k = k_ref[0, :, g, :]                               # (page_size, d)
+        v = v_ref[0, :, g, :]
+        if quantized:
+            # fused dequant: one f32 scale per page row for this kv head
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32) * ks_ref[0, :, g:g + 1]
+            v = v.astype(jnp.float32) * vs_ref[0, :, g:g + 1]
+        v = jnp.where(row_valid, v, jnp.zeros_like(v))
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                           # (rep, page_size)
+        if softcap > 0:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[g]                                   # (rep, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[g] = m_new
+        acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(pj == np_ - 1)
     def _finish():
-        l = jnp.maximum(l_ref[0], 1e-37)
-        o_ref[0, 0, 0, :] = (acc_ref[0] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-37)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -143,42 +156,40 @@ def paged_attention(
         scale=float(scale), quantized=quantized,
     )
     page_spec = pl.BlockSpec(
-        (1, page_size, 1, d),
-        lambda bi, hi, pj, pt, lens, w: (_page(pj, pt, lens, bi), 0, hi // rep, 0),
+        (1, page_size, kvh, d),
+        lambda bi, pj, pt, lens, w: (_page(pj, pt, lens, bi), 0, 0, 0),
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, d), lambda bi, hi, pj, pt, lens, w: (bi, 0, hi, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = [q, k_pages, v_pages]
+    group_spec = pl.BlockSpec(
+        (1, kvh, rep, d), lambda bi, pj, pt, lens, w: (bi, 0, 0, 0)
+    )
+    in_specs = [group_spec, page_spec, page_spec]
+    # q heads are kv-group-major (head = g*rep + r): grouping is a reshape
+    operands = [q.reshape(b, kvh, rep, d), k_pages, v_pages]
     if quantized:
         # scale blocks ride the same page-table index map as their pages
         scale_spec = pl.BlockSpec(
-            (1, page_size, 1),
-            lambda bi, hi, pj, pt, lens, w: (_page(pj, pt, lens, bi), 0, hi // rep),
+            (1, page_size, kvh),
+            lambda bi, pj, pt, lens, w: (_page(pj, pt, lens, bi), 0, 0),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, h, ns),
+        grid=(b, ns),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, d), lambda bi, hi, pj, pt, lens, w: (bi, 0, hi, 0)
-        ),
+        out_specs=group_spec,
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, rep, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
@@ -187,3 +198,4 @@ def paged_attention(
         wval,
         *operands,
     )
+    return out.reshape(b, 1, h, d)

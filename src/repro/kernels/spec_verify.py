@@ -13,18 +13,19 @@ so per-query causal masking is on *absolute* positions: query ``w`` of row
 ``b`` sits at ``lengths[b] + w`` and attends every position ``<= lengths[b]
 + w`` (committed context plus the causal prefix of its own window).
 
-Grid = (batch, q_heads, kv_pages) with the page dimension innermost and
-sequential so the online-softmax state (one row per window position) lives
-in VMEM scratch — the same flash-decode layout as
-:mod:`.paged_attention`, with a (W, d) q block instead of (1, d).  The page
-table, committed ``lengths`` and per-row ``window_lens`` arrive as scalar
+Grid = (batch, kv_pages) with the page dimension innermost and sequential
+so the online-softmax state (one row per window position and query head)
+lives in VMEM scratch — the same flash-decode layout as
+:mod:`.paged_attention`: each program streams one page with every kv head,
+``(page_size, kvh, d)``, and a static loop over the kv heads scores it
+against that group's ``(W * rep, d)`` query rows (the window laid out
+group-major by the wrapper, row ``w * rep + r``).  The page table,
+committed ``lengths`` and per-row ``window_lens`` arrive as scalar
 prefetch: the k/v BlockSpec index maps dereference the page table so only
 pages holding live-or-in-flight tokens stream HBM->VMEM; trailing dead
 blocks clamp to the last live page (a revisit — no new DMA).  ``W`` is
 static (one jit variant per draft depth k), rows with fewer real drafts
-mask the tail and emit exact zeros there.  Pallas wants block minor dims at
-8x128 multiples on real TPUs; the engine's small test/CI window and head
-sizes rely on interpret mode exactly like the paged decode kernel.
+mask the tail and emit exact zeros there.
 
 Quantized pools (``k_scales``/``v_scales`` given): the float32 per-row
 per-kv-head scale blocks stream through the same page-table index map as
@@ -41,10 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; bridge both
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover - version compat
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -53,12 +50,12 @@ def _kernel(
     lens_ref,                  # scalar prefetch: (b,) committed tokens
     wlens_ref,                 # scalar prefetch: (b,) real window tokens
     w_ref,                     # scalar prefetch: (1,) int32 window (0 = none)
-    q_ref,                     # (1, W, 1, d)
-    k_ref, v_ref,              # (1, page_size, 1, d) — one page
-    *rest,                     # [ks_ref, vs_ref (1, page_size, 1)], o_ref, scratch
+    q_ref,                     # (1, kvh, W * rep, d)
+    k_ref, v_ref,              # (1, page_size, kvh, d) — one page, every kv head
+    *rest,                     # [ks_ref, vs_ref (1, page_size, kvh)], o_ref, scratch
     softcap: float,
     page_size: int,
-    win: int,                  # static window rows W
+    rep: int,                  # query heads per kv head
     scale: float,
     quantized: bool,
 ):
@@ -67,8 +64,9 @@ def _kernel(
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     bi = pl.program_id(0)
-    pj = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    pj = pl.program_id(1)
+    np_ = pl.num_programs(1)
+    _, kvh, rows, d = q_ref.shape
 
     @pl.when(pj == 0)
     def _init():
@@ -76,55 +74,62 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]                                   # (W, d)
-    k = k_ref[0, :, 0, :]                                   # (page_size, d)
-    v = v_ref[0, :, 0, :]
-    if quantized:
-        # fused dequant: one f32 scale per page row for this kv head
-        k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
     L = lens_ref[bi]
     wl = wlens_ref[bi]
     # positions are *logical*: page pj of this request covers
     # [pj*page_size, (pj+1)*page_size) regardless of the physical page the
-    # index map streamed in.  Query w sits at absolute position L + w.
+    # index map streamed in.  Query row w*rep + r sits at position L + w.
     k_pos = pj * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (win, page_size), 1
+        jnp.int32, (rows, page_size), 1
     )
-    w_idx = jax.lax.broadcasted_iota(jnp.int32, (win, page_size), 0)
+    w_idx = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0) // rep
     q_pos = L + w_idx
-    valid = (k_pos <= q_pos) & (w_idx < wl)
     w = w_ref[0]
-    valid &= jnp.where(w > 0, (q_pos - k_pos) < w, True)
-    # zero invalid V rows: dead pages hold undefined memory and fully-masked
-    # q rows accumulate p=1 over dead stages — 0-valued V keeps them inert
-    row_valid = jnp.max(valid, axis=0)
-    v = jnp.where(row_valid[:, None], v, 0.0)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                               # (W, page_size)
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    # explicit p mask: a fully-masked q row (window pad / idle slot) has
-    # every score at NEG_INF, so exp(s - m) would be 1 everywhere; masked p
-    # keeps l at 0 -> output exactly 0 for those rows
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    # (w <= 0) | ...: Mosaic cannot select between boolean vectors
+    valid = (k_pos <= q_pos) & (w_idx < wl) & ((w <= 0) | (q_pos - k_pos < w))
+    # zero V rows no query may read: dead pages hold undefined memory and
+    # fully-masked q rows accumulate p=0 * garbage — 0-valued V keeps them
+    # inert.  Row t is readable iff some window query sees it, i.e. it is
+    # committed or in flight and inside the sliding window of the last query
+    row_pos = pj * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, d), 0
     )
+    last_q = L + wl - 1
+    row_valid = (row_pos <= last_q) & ((w <= 0) | (L - row_pos < w))
+    for g in range(kvh):                      # static: one MXU pass per group
+        q = q_ref[0, g]                                     # (W * rep, d)
+        k = k_ref[0, :, g, :]                               # (page_size, d)
+        v = v_ref[0, :, g, :]
+        if quantized:
+            # fused dequant: one f32 scale per page row for this kv head
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32) * ks_ref[0, :, g:g + 1]
+            v = v.astype(jnp.float32) * vs_ref[0, :, g:g + 1]
+        v = jnp.where(row_valid, v, jnp.zeros_like(v))
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                           # (W*rep, page_size)
+        if softcap > 0:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[g]                                   # (W*rep, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit p mask: a fully-masked q row (window pad / idle slot) has
+        # every score at NEG_INF, so exp(s - m) would be 1 everywhere; masked
+        # p keeps l at 0 -> output exactly 0 for those rows
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[g] = m_new
+        acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(pj == np_ - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-37)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def spec_verify(
@@ -168,54 +173,51 @@ def spec_verify(
         return pt[bi, jnp.minimum(pj, last)]
 
     kernel = functools.partial(
-        _kernel, softcap=float(softcap), page_size=page_size, win=W,
+        _kernel, softcap=float(softcap), page_size=page_size, rep=rep,
         scale=float(scale), quantized=quantized,
     )
     page_spec = pl.BlockSpec(
-        (1, page_size, 1, d),
-        lambda bi, hi, pj, pt, lens, wlens, w: (
-            _page(pj, pt, lens, wlens, bi), 0, hi // rep, 0
+        (1, page_size, kvh, d),
+        lambda bi, pj, pt, lens, wlens, w: (
+            _page(pj, pt, lens, wlens, bi), 0, 0, 0
         ),
     )
-    in_specs = [
-        pl.BlockSpec(
-            (1, W, 1, d),
-            lambda bi, hi, pj, pt, lens, wlens, w: (bi, 0, hi, 0),
-        ),
-        page_spec,
-        page_spec,
-    ]
-    operands = [q, k_pages, v_pages]
+    group_spec = pl.BlockSpec(
+        (1, kvh, W * rep, d), lambda bi, pj, pt, lens, wlens, w: (bi, 0, 0, 0)
+    )
+    in_specs = [group_spec, page_spec, page_spec]
+    # group-major window: q heads are kv-group-major (head = g*rep + r), so
+    # (b, W, kvh, rep, d) -> (b, kvh, W*rep, d) puts each group's window
+    # rows in one contiguous (W*rep, d) tile
+    qg = q.reshape(b, W, kvh, rep, d).transpose(0, 2, 1, 3, 4)
+    operands = [qg.reshape(b, kvh, W * rep, d), k_pages, v_pages]
     if quantized:
         # scale blocks ride the same page-table index map as their pages
         scale_spec = pl.BlockSpec(
-            (1, page_size, 1),
-            lambda bi, hi, pj, pt, lens, wlens, w: (
-                _page(pj, pt, lens, wlens, bi), 0, hi // rep
+            (1, page_size, kvh),
+            lambda bi, pj, pt, lens, wlens, w: (
+                _page(pj, pt, lens, wlens, bi), 0, 0
             ),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, h, ns),
+        grid=(b, ns),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, W, 1, d),
-            lambda bi, hi, pj, pt, lens, wlens, w: (bi, 0, hi, 0),
-        ),
+        out_specs=group_spec,
         scratch_shapes=[
-            pltpu.VMEM((W,), jnp.float32),
-            pltpu.VMEM((W,), jnp.float32),
-            pltpu.VMEM((W, d), jnp.float32),
+            pltpu.VMEM((kvh, W * rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, W * rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, W * rep, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, W, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, W * rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
@@ -225,3 +227,5 @@ def spec_verify(
         wval,
         *operands,
     )
+    out = out.reshape(b, kvh, W, rep, d).transpose(0, 2, 1, 3, 4)
+    return out.reshape(b, W, h, d)

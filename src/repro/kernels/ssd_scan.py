@@ -24,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; bridge both
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover - version compat
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 
 def _kernel(
     x_ref,      # (1, Q, h, p)

@@ -20,16 +20,20 @@ and the serving engine):
   ``[0, chunk_pos0[c])``, held in the first ``chunk_pos0[c]/page_size``
   entries of ``page_tables[c]``.
 
-Grid = (q_blocks, heads, stages) with the stage dimension innermost and
-sequential so the online-softmax state lives in VMEM scratch.  Stage
-``s < ctx_bound`` streams context page ``page_tables[c, s]`` from the pool;
-stage ``s >= ctx_bound`` streams the chunk's own packed K/V block
+Grid = (q_blocks, stages) with the stage dimension innermost and
+sequential so the online-softmax state lives in VMEM scratch.  Every block
+carries every head: the wrapper lays the queries out group-major, ``(kvh,
+T * rep, d)`` with row ``t * rep + r``, so a q block is ``(kvh, block *
+rep, d)``, and K/V blocks are ``(block, kvh, d)`` (packed chunk) or
+``(1, page_size, kvh, d)`` (context page).  A static loop over the kv heads
+runs one ``(block * rep, d) x (d, block)`` matmul per group, and each
+context page streams once per q block instead of once per query head.
+Stage ``s < ctx_bound`` streams context page ``page_tables[c, s]`` from the
+pool; stage ``s >= ctx_bound`` streams the chunk's own packed K/V block
 ``start_blk[c] + (s - ctx_bound)``.  All per-chunk metadata arrives via
 scalar prefetch so the BlockSpec index maps dereference only live
 pages/blocks — dead stages clamp to the previously streamed block, which
-Pallas recognises as a revisit (no new DMA).  Pallas wants the block minor
-dims at 8×128 multiples on real TPUs; the engine's small test/CI page sizes
-rely on interpret mode exactly like the paged decode kernel.
+Pallas recognises as a revisit (no new DMA).
 
 Quantized pools (``k_scales``/``v_scales`` given): only the CONTEXT page
 stages dequantize — the packed chunk K/V (current activations) stay full
@@ -47,10 +51,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; bridge both
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover - version compat
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -61,12 +61,13 @@ def _kernel(
     lens_ref,                  # scalar prefetch: (C,) real tokens per chunk
     pt_ref,                    # scalar prefetch: (C, max_pages) page tables
     w_ref,                     # scalar prefetch: (1,) window (0 = none)
-    q_ref,                     # (1, block, 1, d)
-    kc_ref, vc_ref,            # (1, block, 1, d) — packed chunk K/V block
-    kp_ref, vp_ref,            # (1, block, 1, d) — one context page
-    *rest,                     # [kps_ref, vps_ref (1, block, 1)], o_ref, scratch
+    q_ref,                     # (kvh, block * rep, d) group-major queries
+    kc_ref, vc_ref,            # (block, kvh, d) — packed chunk K/V block
+    kp_ref, vp_ref,            # (1, block, kvh, d) — one context page
+    *rest,                     # [kps_ref, vps_ref (1, block, kvh)], o_ref, scratch
     softcap: float,
     block: int,
+    rep: int,                  # query heads per kv head
     ctx_bound: int,
     scale: float,
     quantized: bool,
@@ -76,8 +77,9 @@ def _kernel(
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     qj = pl.program_id(0)
-    s = pl.program_id(2)
-    ns = pl.num_programs(2)
+    s = pl.program_id(1)
+    ns = pl.num_programs(1)
+    kvh, rows, d = q_ref.shape
 
     @pl.when(s == 0)
     def _init():
@@ -89,68 +91,76 @@ def _kernel(
     seq_len = lens_ref[c]
     pos0 = pos0_ref[c]
     # chunk-local offset / absolute position of each q row in this block
+    # (row t*rep + r is token t of the block)
     off_q = (qj - start_blk_ref[c]) * block + jax.lax.broadcasted_iota(
-        jnp.int32, (block, block), 0
-    )
+        jnp.int32, (rows, block), 0
+    ) // rep
     q_pos = pos0 + off_q
     q_valid = off_q < seq_len
 
     is_ctx = s < ctx_bound
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
     # context stage: page s covers logical positions [s*block, (s+1)*block)
-    ctx_pos = s * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    ctx_pos = s * block + col
     ctx_valid = ctx_pos < pos0
     # intra stage: packed block t of this chunk covers chunk-local offsets
     # [t*block, (t+1)*block) at absolute positions pos0 + those offsets
     t = s - ctx_bound
-    off_k = t * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    off_k = t * block + col
     k_pos_in = pos0 + off_k
     intra_valid = (off_k < seq_len) & (q_pos >= k_pos_in)
 
     k_pos = jnp.where(is_ctx, ctx_pos, k_pos_in)
-    valid = q_valid & jnp.where(is_ctx, ctx_valid, intra_valid)
+    # boolean algebra, not where(): Mosaic cannot select between i1 vectors
+    valid = q_valid & ((is_ctx & ctx_valid) | (~is_ctx & intra_valid))
     w = w_ref[0]
-    valid &= jnp.where(w > 0, (q_pos - k_pos) < w, True)
-
-    q = q_ref[0, :, 0, :]                                   # (block, d)
-    if quantized:
-        # fused dequant of the CONTEXT page only (packed chunk K/V are the
-        # current activations and stay full precision)
-        kp = kp_ref[0, :, 0, :].astype(jnp.float32) * kps_ref[0, :, 0][:, None]
-        vp = vp_ref[0, :, 0, :].astype(jnp.float32) * vps_ref[0, :, 0][:, None]
-        k = jnp.where(is_ctx, kp, kc_ref[0, :, 0, :].astype(jnp.float32))
-        v = jnp.where(is_ctx, vp, vc_ref[0, :, 0, :].astype(jnp.float32))
-    else:
-        k = jnp.where(is_ctx, kp_ref[0, :, 0, :], kc_ref[0, :, 0, :])
-        v = jnp.where(is_ctx, vp_ref[0, :, 0, :], vc_ref[0, :, 0, :])
-    # zero invalid V rows: dead blocks hold undefined memory and pad q rows
-    # accumulate p=1 over fully-masked stages — 0-valued V keeps them inert
-    row_valid = jnp.max(valid, axis=0)
-    v = jnp.where(row_valid[:, None], v, 0.0)
-    s_qk = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                               # (block, block)
-    if softcap > 0:
-        s_qk = softcap * jnp.tanh(s_qk / softcap)
-    s_qk = jnp.where(valid, s_qk, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s_qk, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    # explicit p mask: a fully-masked q row (chunk/buffer pad) has every
-    # score at NEG_INF, so exp(s - m) would be 1 everywhere and accumulate
-    # the OTHER rows' valid V columns; masked p keeps l at 0 -> output 0
-    p = jnp.where(valid, jnp.exp(s_qk - m_new[:, None]), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    valid &= (w <= 0) | ((q_pos - k_pos) < w)
+    # zero V rows that hold no token (dead blocks hold undefined memory):
+    # masked p is exactly 0, but 0 * NaN is not
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, d), 0)
+    row_valid = (is_ctx & (s * block + row < pos0)) | (
+        ~is_ctx & (t * block + row < seq_len)
     )
+
+    for g in range(kvh):                      # static: one MXU pass per group
+        q = q_ref[g]                                        # (block*rep, d)
+        if quantized:
+            # fused dequant of the CONTEXT page only (packed chunk K/V are
+            # the current activations and stay full precision)
+            q = q.astype(jnp.float32)
+            kp = kp_ref[0, :, g, :].astype(jnp.float32) * kps_ref[0, :, g:g + 1]
+            vp = vp_ref[0, :, g, :].astype(jnp.float32) * vps_ref[0, :, g:g + 1]
+            k = jnp.where(is_ctx, kp, kc_ref[:, g, :].astype(jnp.float32))
+            v = jnp.where(is_ctx, vp, vc_ref[:, g, :].astype(jnp.float32))
+        else:
+            k = jnp.where(is_ctx, kp_ref[0, :, g, :], kc_ref[:, g, :])
+            v = jnp.where(is_ctx, vp_ref[0, :, g, :], vc_ref[:, g, :])
+        v = jnp.where(row_valid, v, jnp.zeros_like(v))
+        s_qk = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                           # (block*rep, block)
+        if softcap > 0:
+            s_qk = softcap * jnp.tanh(s_qk / softcap)
+        s_qk = jnp.where(valid, s_qk, NEG_INF)
+        m_prev = m_ref[g]                                   # (block*rep, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s_qk, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit p mask: a fully-masked q row (chunk/buffer pad) has every
+        # score at NEG_INF, so exp(s - m) would be 1 everywhere and
+        # accumulate the OTHER rows' valid V columns; masked p keeps l at 0
+        # -> output 0
+        p = jnp.where(valid, jnp.exp(s_qk - m_new), 0.0)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[g] = m_new
+        acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(s == ns - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-37)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def varlen_prefill(
@@ -217,70 +227,58 @@ def varlen_prefill(
         return sblk[c] + jnp.clip(s - ctx_bound, 0, qj - sblk[c])
 
     kernel = functools.partial(
-        _kernel, softcap=float(softcap), block=block, ctx_bound=ctx_bound,
-        scale=float(scale), quantized=quantized,
+        _kernel, softcap=float(softcap), block=block, rep=rep,
+        ctx_bound=ctx_bound, scale=float(scale), quantized=quantized,
     )
-    in_specs = [
-        pl.BlockSpec(
-            (1, block, 1, d),
-            lambda qj, hi, s, blkc, sblk, pos0, lens, pt, w: (0, qj, hi, 0),
+    group_spec = pl.BlockSpec(
+        (kvh, block * rep, d),
+        lambda qj, s, blkc, sblk, pos0, lens, pt, w: (0, qj, 0),
+    )
+    intra_spec = pl.BlockSpec(
+        (block, kvh, d),
+        lambda qj, s, blkc, sblk, pos0, lens, pt, w: (
+            _intra_blk(qj, s, blkc, sblk), 0, 0
         ),
-        pl.BlockSpec(
-            (1, block, 1, d),
-            lambda qj, hi, s, blkc, sblk, pos0, lens, pt, w: (
-                0, _intra_blk(qj, s, blkc, sblk), hi // rep, 0
-            ),
+    )
+    ctx_spec = pl.BlockSpec(
+        (1, block, kvh, d),
+        lambda qj, s, blkc, sblk, pos0, lens, pt, w: (
+            _ctx_page(qj, s, blkc, sblk, pos0, lens, pt), 0, 0, 0
         ),
-        pl.BlockSpec(
-            (1, block, 1, d),
-            lambda qj, hi, s, blkc, sblk, pos0, lens, pt, w: (
-                0, _intra_blk(qj, s, blkc, sblk), hi // rep, 0
-            ),
-        ),
-        pl.BlockSpec(
-            (1, block, 1, d),
-            lambda qj, hi, s, blkc, sblk, pos0, lens, pt, w: (
-                _ctx_page(qj, s, blkc, sblk, pos0, lens, pt), 0, hi // rep, 0
-            ),
-        ),
-        pl.BlockSpec(
-            (1, block, 1, d),
-            lambda qj, hi, s, blkc, sblk, pos0, lens, pt, w: (
-                _ctx_page(qj, s, blkc, sblk, pos0, lens, pt), 0, hi // rep, 0
-            ),
-        ),
-    ]
-    operands = [q[None], k[None], v[None], k_pages, v_pages]
+    )
+    in_specs = [group_spec, intra_spec, intra_spec, ctx_spec, ctx_spec]
+    # group-major queries: q heads are kv-group-major (head = g*rep + r), so
+    # (T, kvh, rep, d) -> (kvh, T*rep, d) puts each group's block rows in one
+    # contiguous (block*rep, d) tile
+    qg = q.reshape(T, kvh, rep, d).transpose(1, 0, 2, 3).reshape(kvh, T * rep, d)
+    operands = [qg, k, v, k_pages, v_pages]
     if quantized:
         # scale blocks ride the same context-page index map as their pages
         scale_spec = pl.BlockSpec(
-            (1, block, 1),
-            lambda qj, hi, s, blkc, sblk, pos0, lens, pt, w: (
-                _ctx_page(qj, s, blkc, sblk, pos0, lens, pt), 0, hi // rep
+            (1, block, kvh),
+            lambda qj, s, blkc, sblk, pos0, lens, pt, w: (
+                _ctx_page(qj, s, blkc, sblk, pos0, lens, pt), 0, 0
             ),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(nqb, h, ctx_bound + nqb),
+        grid=(nqb, ctx_bound + nqb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, block, 1, d),
-            lambda qj, hi, s, blkc, sblk, pos0, lens, pt, w: (0, qj, hi, 0),
-        ),
+        out_specs=group_spec,
         scratch_shapes=[
-            pltpu.VMEM((block,), jnp.float32),
-            pltpu.VMEM((block,), jnp.float32),
-            pltpu.VMEM((block, d), jnp.float32),
+            pltpu.VMEM((kvh, block * rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, block * rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, block * rep, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, T, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((kvh, T * rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
@@ -292,4 +290,4 @@ def varlen_prefill(
         wval,
         *operands,
     )
-    return out[0]
+    return out.reshape(kvh, T, rep, d).transpose(1, 0, 2, 3).reshape(T, h, d)

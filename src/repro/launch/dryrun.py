@@ -1,11 +1,17 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ the required first two lines: set BEFORE any jax-importing import below.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
+).strip()
+# ^ the required first lines: set BEFORE any jax-importing import below.
 """Multi-pod dry-run: AOT lower + compile every (arch × shape × mesh) cell.
 
-The FIRST TWO LINES above run before any other import (jax locks the device
-count at first init). Do not import this module from code that needs real
-device topology.
+The FIRST LINES above run before any other import (jax locks the platform
+and the device count at first init): the dry-run is a CPU program over 512
+forced host devices, so it pins itself to the CPU — it never holds a chip,
+and neither do the per-cell processes ``--all`` starts, which inherit the
+pin.  The device-count flag is appended to any ``XLA_FLAGS`` already set.
+Do not import this module from code that needs real device topology.
 
 For every cell this lowers the right step function (train_step for
 ``train_*`` shapes, prefill/decode for serving shapes) with

@@ -13,17 +13,11 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # axis_types only exists on newer jax; plain Auto axes are the default
-    # everywhere, so drop the kwarg when the installed version lacks it.
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(
-                shape, axes,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    # Auto axes: shardings come from the rules' PartitionSpecs and GSPMD
+    # (jax.make_mesh defaults to Explicit axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,23 +26,26 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_host_mesh(tp: int | None = None):
-    """Host (CPU) serving mesh: ``(data=1, model=tp)``.
-
-    ``tp`` > 1 needs forced host devices — run under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before jax
-    initializes its backend) so ``jax.devices()`` exposes enough CPU
-    "chips" to fill the model axis.
+def make_serve_mesh(tp: int | None = None):
+    """Serving mesh ``(data=1, model=tp)`` over the first ``tp`` of this
+    process's devices (``jax.devices()``): the chips of a TPU host, or CPU
+    devices elsewhere.  A CPU process shows more than one device only when
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` is set before jax
+    initializes its backend — the way tests exercise tp > 1 without chips.
     """
     tp = 1 if tp is None else int(tp)
     if tp < 1:
         raise ValueError(f"tp must be >= 1, got {tp}")
     n = jax.device_count()
     if tp > n:
+        platform = jax.default_backend()
+        hint = (
+            f"; set XLA_FLAGS=--xla_force_host_platform_device_count={tp} "
+            f"before the process starts" if platform == "cpu" else ""
+        )
         raise ValueError(
-            f"tp={tp} needs {tp} devices but only {n} visible; set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={tp} "
-            f"before the process starts"
+            f"tp={tp} needs {tp} devices but only {n} {platform} "
+            f"device(s) are visible{hint}"
         )
     return _make_mesh((1, tp), ("data", "model"))
 

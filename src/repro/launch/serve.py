@@ -38,9 +38,11 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from ..configs import get_config
+from ..configs import depth_cut, get_config
 from ..core.analysis import (
     fleet_section,
     latency_summary,
@@ -69,6 +71,8 @@ from ..serve.scheduler import (
     SchedulerConfig,
     TenantSpec,
 )
+from ..sharding.specs import param_pspecs
+from .compile_cache import enable_compile_cache
 
 
 def _parse_tenants(s: str):
@@ -431,11 +435,24 @@ def _serve_fleet(engines, cfg, args, load, prompts):
     return summary, stats.total_tokens, stats.wall_s
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4-9b")
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--backend", default="flash")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the architecture's small CPU test config "
+                         "(default); --no-reduced serves its published "
+                         "widths with weights and KV pages in bfloat16")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut: serve the first N layers at unchanged "
+                         "widths, one chip's share of a deployment that "
+                         "pipelines the rest over further chips (0 = every "
+                         "layer)")
+    ap.add_argument("--backend", default=None,
+                    choices=["pallas", "flash", "ref"],
+                    help="attention kernels (default: the platform's own — "
+                         "pallas on a TPU, flash elsewhere; flash and ref "
+                         "are oracles)")
     ap.add_argument(
         "--engine", "--mode", dest="engine", default="static",
         choices=["static", "continuous", "paged"],
@@ -470,9 +487,10 @@ def main(argv=None) -> int:
                     help="paged admission overcommit factor (>1 admits past "
                          "worst-case page commitment; preemption is the valve)")
     ap.add_argument("--tp", type=int, default=1,
-                    help="tensor-parallel degree over the mesh 'model' axis "
-                         "(1 = single device; CPU testing needs XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=N)")
+                    help="tensor-parallel degree: heads split over the "
+                         "first N of the process's devices (the chips of a "
+                         "TPU host; on CPU, N forced host devices via "
+                         "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     ap.add_argument("--rs-block-outputs", action="store_true",
                     help="reduce-scatter block outputs instead of all-reduce "
                          "on seq-shardable (prefill) launches")
@@ -552,28 +570,21 @@ def main(argv=None) -> int:
                          "step 4 — every live slot migrates with zero "
                          "recompute before the worker is removed")
     ap.add_argument("--evaldb", default="")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the command line."""
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.prefix_len > 0 and args.prefix_len >= args.prompt_len:
         ap.error(
             f"--prefix-len {args.prefix_len} must be smaller than "
             f"--prompt-len {args.prompt_len} (the shared prefix is a strict "
             f"prefix; every prompt keeps a unique tail)"
         )
-    cfg = get_config(args.arch, reduced=args.reduced)
-    model = build_model(cfg, backend=args.backend)
-    params = model.init(jax.random.PRNGKey(0))
-    rules = None
-    if args.tp > 1:
-        if args.engine != "paged":
-            ap.error("--tp > 1 requires --engine paged")
-        from ..sharding.specs import serve_rules
-        from .mesh import make_host_mesh
-
-        rules = serve_rules(
-            make_host_mesh(tp=args.tp),
-            rs_block_outputs=args.rs_block_outputs,
-        )
+    if args.tp > 1 and args.engine != "paged":
+        ap.error("--tp > 1 requires --engine paged")
     if args.kv_dtype and args.engine != "paged":
         ap.error("--kv-dtype requires --engine paged (only the paged pool "
                  "stores quantized KV pages)")
@@ -593,34 +604,62 @@ def main(argv=None) -> int:
             _parse_priority_mix(args.priority_mix)
         except ValueError as e:
             ap.error(f"bad --priority-mix {args.priority_mix!r}: {e}")
+    try:
+        depth_cut(get_config(args.arch, reduced=args.reduced), args.layers)
+    except ValueError as e:
+        ap.error(str(e))
+    return args
 
-    def make_engine():
-        return ServingEngine(
-            model, params, max_batch=args.engine_batch, max_seq=args.max_seq,
-            page_size=args.page_size, rules=rules,
-            kv_dtype=args.kv_dtype or None,
-        )
 
-    engine = make_engine()
-    # report header: the engine knobs this evaluation ran under, so the run
-    # is self-describing (same block lands in the evaldb record)
-    knobs = EngineKnobs(
-        engine=args.engine,
-        kv_dtype=args.kv_dtype or engine.cache_dtype,
-        page_size=args.page_size if args.engine == "paged" else 0,
-        spec_k=args.spec_k if args.engine == "paged" else 0,
-        prefix_cache=args.engine == "paged" and args.prefix_cache == "on",
-        tp=engine.tp,
-        # recovery knobs are fleet-level: single-engine runs keep the
-        # pre-fleet header byte-for-byte
-        recovery=args.recovery if args.fleet else "replay",
-        checkpoint_every=args.checkpoint_every if args.fleet else 0,
+def make_rules(args):
+    """Tensor-parallel sharding rules over the first ``--tp`` devices, or
+    None for a single device."""
+    if args.tp <= 1:
+        return None
+    from ..sharding.specs import serve_rules
+    from .mesh import make_serve_mesh
+
+    return serve_rules(
+        make_serve_mesh(tp=args.tp), rs_block_outputs=args.rs_block_outputs
     )
-    print(f"[serve] {knobs.describe()}")
-    if args.tp > 1:
-        print(f"[serve] tensor parallelism: requested tp={args.tp}, "
-              f"effective tp={engine.tp} "
-              f"({'heads split' if engine.tp > 1 else 'replication fallback'})")
+
+
+def serve_dtype(args) -> str:
+    """Weights and KV pages: bfloat16 at published widths (what a chip
+    serves), float32 for the reduced CPU config (what the tests compare
+    bit for bit)."""
+    return "float32" if args.reduced else "bfloat16"
+
+
+def load_model(args, rules=None):
+    """(cfg, model, params): the configured architecture, cut to
+    ``--layers``, with random weights from seed 0.  The weights are made on
+    the device by one jitted init, already laid out under ``rules`` — a
+    model larger than one chip never lands whole on the first device."""
+    cfg = depth_cut(get_config(args.arch, reduced=args.reduced), args.layers)
+    model = build_model(cfg, backend=args.backend)
+    shardings = None
+    if rules is not None:
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(rules.mesh, spec),
+            param_pspecs(model.param_defs(), rules),
+            is_leaf=lambda x: isinstance(x, PartitionSpec),
+        )
+    init = jax.jit(model.init, static_argnums=1, out_shardings=shardings)
+    params = init(jax.random.PRNGKey(0), jnp.dtype(serve_dtype(args)))
+    return cfg, model, params
+
+
+def make_engine(args, model, params, rules=None) -> ServingEngine:
+    return ServingEngine(
+        model, params, max_batch=args.engine_batch, max_seq=args.max_seq,
+        cache_dtype=serve_dtype(args), page_size=args.page_size, rules=rules,
+        kv_dtype=args.kv_dtype or None,
+    )
+
+
+def make_workload(args, cfg):
+    """(load, prompts): arrivals and prompt token ids drawn from seed 0."""
     rng = np.random.default_rng(0)
     if args.tenants:
         # multi-tenant mix: superposed per-tenant Poisson streams carrying
@@ -672,18 +711,61 @@ def main(argv=None) -> int:
         mrng = _random.Random(0)
         for r in load:
             r.tags["priority"] = mrng.choices(tiers, weights)[0]
+    return load, prompts
 
+
+def serve(args, engine, cfg, load, prompts, model=None, params=None,
+          rules=None):
+    """Run the workload through the ``--engine`` executor (or the fleet);
+    returns (summary, generated tokens, wall seconds)."""
     if args.fleet > 0:
         # workers share model+params (weights are read-only under serving);
         # each gets its own engine => its own KV page pool + slot state
-        engines = [engine] + [make_engine() for _ in range(args.fleet - 1)]
-        summary, generated, wall = _serve_fleet(engines, cfg, args, load, prompts)
-    elif args.engine == "continuous":
-        summary, generated, wall = _serve_continuous(engine, cfg, args, load, prompts)
-    elif args.engine == "paged":
-        summary, generated, wall = _serve_paged(engine, cfg, args, load, prompts)
-    else:
-        summary, generated, wall = _serve_static(engine, cfg, args, load, prompts)
+        engines = [engine] + [
+            make_engine(args, model, params, rules)
+            for _ in range(args.fleet - 1)
+        ]
+        return _serve_fleet(engines, cfg, args, load, prompts)
+    if args.engine == "continuous":
+        return _serve_continuous(engine, cfg, args, load, prompts)
+    if args.engine == "paged":
+        return _serve_paged(engine, cfg, args, load, prompts)
+    return _serve_static(engine, cfg, args, load, prompts)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
+    rules = make_rules(args)
+    cfg, model, params = load_model(args, rules)
+    engine = make_engine(args, model, params, rules)
+    # report header: the engine knobs this evaluation ran under, so the run
+    # is self-describing (same block lands in the evaldb record)
+    knobs = EngineKnobs(
+        engine=args.engine,
+        kv_dtype=args.kv_dtype or engine.cache_dtype,
+        page_size=args.page_size if args.engine == "paged" else 0,
+        spec_k=args.spec_k if args.engine == "paged" else 0,
+        prefix_cache=args.engine == "paged" and args.prefix_cache == "on",
+        tp=engine.tp,
+        # recovery knobs are fleet-level: single-engine runs keep the
+        # pre-fleet header byte-for-byte
+        recovery=args.recovery if args.fleet else "replay",
+        checkpoint_every=args.checkpoint_every if args.fleet else 0,
+    )
+    dev = jax.devices()[0]
+    print(f"[serve] {cfg.name} ({cfg.num_layers} layers): {model.backend} "
+          f"kernels on {jax.device_count()} x {dev.platform} "
+          f"({dev.device_kind})")
+    print(f"[serve] {knobs.describe()}")
+    if args.tp > 1:
+        print(f"[serve] tensor parallelism: requested tp={args.tp}, "
+              f"effective tp={engine.tp} "
+              f"({'heads split' if engine.tp > 1 else 'replication fallback'})")
+    load, prompts = make_workload(args, cfg)
+    summary, generated, wall = serve(
+        args, engine, cfg, load, prompts, model, params, rules
+    )
 
     print(f"[serve] {len(load)} requests, {generated} tokens in {wall:.2f}s")
     for k, v in summary.items():
@@ -691,7 +773,7 @@ def main(argv=None) -> int:
     if args.evaldb:
         EvalDB(args.evaldb).insert(
             EvaluationRecord(
-                model=cfg.name, model_version="1.0.0", backend=args.backend,
+                model=cfg.name, model_version="1.0.0", backend=model.backend,
                 backend_version="1.0.0", system="local",
                 scenario=f"serve-fleet{args.fleet}" if args.fleet > 0
                 else f"serve-{args.engine}",

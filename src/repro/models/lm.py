@@ -56,12 +56,13 @@ class BaseModel:
     def __init__(
         self,
         cfg: ArchConfig,
-        backend: str = ops.DEFAULT_BACKEND,
+        backend: Optional[str] = None,
         compute_dtype=None,
     ) -> None:
         cfg.validate()
         self.cfg = cfg
-        self.backend = backend
+        # None: the platform's own kernels (ops.default_backend)
+        self.backend = backend or ops.default_backend()
         # mixed precision: weights cast per-layer inside the scan body so only
         # one layer's low-precision copy is live at a time
         self.compute_dtype = jnp.dtype(compute_dtype) if compute_dtype else None
@@ -1313,7 +1314,7 @@ EncDecLM.forward_instrumented = _forward_instrumented_encdec
 
 
 def build_model(
-    cfg: ArchConfig, backend: str = ops.DEFAULT_BACKEND, compute_dtype=None
+    cfg: ArchConfig, backend: Optional[str] = None, compute_dtype=None
 ) -> BaseModel:
     if cfg.family == "encdec":
         return EncDecLM(cfg, backend, compute_dtype)

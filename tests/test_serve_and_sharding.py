@@ -314,3 +314,69 @@ def test_rank_mismatch_raises():
     rules = default_rules(mesh)
     with pytest.raises(ValueError):
         param_pspecs({"bad": P((2, 2), axes=("embed",))}, rules)
+
+
+# ---------------------------------------------------------------------------
+# launch/serve.py: published widths, depth cut, kernel default, compile cache
+# ---------------------------------------------------------------------------
+def test_depth_cut_keeps_widths_and_whole_periods():
+    from repro.configs import depth_cut
+
+    glm = get_config("glm4-9b")
+    cut = depth_cut(glm, 16)
+    assert cut.num_layers == 16 and cut.name == "glm4-9b-16L"
+    assert (cut.d_model, cut.num_heads, cut.num_kv_heads, cut.d_ff,
+            cut.vocab_size) == (glm.d_model, glm.num_heads,
+                                glm.num_kv_heads, glm.d_ff, glm.vocab_size)
+    assert depth_cut(glm, 0) is glm and depth_cut(glm, 40) is glm
+    gemma = get_config("gemma2-27b")               # local/global pairs
+    assert depth_cut(gemma, 4).num_layers == 4
+    with pytest.raises(ValueError, match="pattern"):
+        depth_cut(gemma, 5)
+
+
+def test_serve_cli_reduced_switch_and_depth():
+    from repro.launch import serve
+
+    args = serve.parse_args([])
+    assert args.reduced and args.backend is None
+    assert serve.serve_dtype(args) == "float32"
+    args = serve.parse_args(["--no-reduced", "--layers", "16"])
+    assert not args.reduced and args.layers == 16
+    assert serve.serve_dtype(args) == "bfloat16"
+    with pytest.raises(SystemExit):
+        serve.parse_args(["--arch", "gemma2-27b", "--no-reduced",
+                          "--layers", "5"])
+
+
+def test_serve_cli_builds_reduced_model_through_its_functions():
+    from repro.kernels import ops
+    from repro.launch import serve
+
+    args = serve.parse_args(["--layers", "2", "--engine", "paged"])
+    cfg, model, params = serve.load_model(args)
+    assert cfg.num_layers == 2
+    # off a TPU the platform's kernels are the chunked pure-JAX ones
+    assert model.backend == ops.default_backend() == "flash"
+    assert params["embed"].dtype == jnp.float32
+    engine = serve.make_engine(args, model, params)
+    assert engine.cache_dtype == "float32"
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_dir(monkeypatch):
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", before)
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CHECKOUT_CACHE_DIR)
+        assert compile_cache.CHECKOUT_CACHE_DIR.name == ".jax_cache"
+        assert (compile_cache.CHECKOUT_CACHE_DIR.parent / "src").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -15,7 +15,7 @@ tp=1 / fallback tests always run.  Coverage:
   ragged lengths, page-straddling contexts, bf16 pools;
 * end-to-end ``serve_paged`` greedy-token bit-identity, tp=2 vs tp=1,
   across packed/chunked x spec_k 0/2 x prefix-cache on/off x preemption;
-* ``make_host_mesh`` and the non-divisible-heads replication fallback.
+* ``make_serve_mesh`` and the non-divisible-heads replication fallback.
 """
 import numpy as np
 import pytest
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.kernels import ops, ref
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_serve_mesh
 from repro.models import build_model
 from repro.serve.engine import ServeRequest, ServingEngine
 from repro.sharding.specs import (
@@ -53,7 +53,7 @@ def _tol(dtype):
 
 
 def _rules_for(tp):
-    return serve_rules(make_host_mesh(tp=tp))
+    return serve_rules(make_serve_mesh(tp=tp))
 
 
 # ---------------------------------------------------------------------------
@@ -192,22 +192,22 @@ def test_paged_attention_tp_pages_bound():
 # mesh + rules plumbing
 # ---------------------------------------------------------------------------
 def test_make_host_mesh_defaults_single_device():
-    mesh = make_host_mesh()
+    mesh = make_serve_mesh()
     assert mesh.axis_names == ("data", "model")
     assert mesh.shape["model"] == 1 and mesh.shape["data"] == 1
 
 
 @requires_devices(2)
 def test_make_host_mesh_tp_axis():
-    mesh = make_host_mesh(tp=2)
+    mesh = make_serve_mesh(tp=2)
     assert mesh.shape["model"] == 2 and mesh.shape["data"] == 1
 
 
 def test_make_host_mesh_rejects_oversized_tp():
     with pytest.raises(ValueError, match="xla_force_host_platform"):
-        make_host_mesh(tp=10 * jax.device_count())
+        make_serve_mesh(tp=10 * jax.device_count())
     with pytest.raises(ValueError):
-        make_host_mesh(tp=0)
+        make_serve_mesh(tp=0)
 
 
 @requires_devices(2)
@@ -352,7 +352,7 @@ def test_serve_paged_tp2_reduce_scatter_lever(_served_model):
                                 num_pages=40)
     server = TracingServer()
     tracer = Tracer("tp-rs", server)
-    rules = serve_rules(make_host_mesh(tp=2), rs_block_outputs=True)
+    rules = serve_rules(make_serve_mesh(tp=2), rs_block_outputs=True)
     eng = ServingEngine(model, params, max_batch=3, max_seq=64, rules=rules)
     got = eng.serve_paged(_requests(cfg), num_slots=3, page_size=8,
                           num_pages=40, tracer=tracer)
@@ -405,3 +405,25 @@ def test_serve_paged_tp2_int8_bit_identical(_served_model):
     by_id = {r.request_id: r for r in base.results}
     for r in got.results:
         np.testing.assert_array_equal(r.tokens, by_id[r.request_id].tokens)
+
+
+def test_bench_tp_decides_from_env_without_jax(monkeypatch):
+    """The tp sweep picks parent-vs-child from the environment alone (a
+    parent that touched JAX would hold a chip its child needs), and only a
+    process pinned to the CPU counts its forced host devices."""
+    from benchmarks.bench_tp import _pinned_host_devices
+
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert _pinned_host_devices() == 8
+    monkeypatch.setenv(
+        "XLA_FLAGS",
+        "--xla_force_host_platform_device_count=2 "
+        "--xla_force_host_platform_device_count=8",
+    )
+    assert _pinned_host_devices() == 8              # the last flag wins
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert _pinned_host_devices() == 0
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.delenv("XLA_FLAGS")
+    assert _pinned_host_devices() == 0
