@@ -7,10 +7,19 @@ aggregates all events into a single timeline on a *tracing server*.
 Here the stack levels adapt to JAX/TPU:
 
   MODEL      spans around pipeline operators (pre-process, predict, post-process)
-  FRAMEWORK  spans around jit/AOT executions and per-layer ``named_scope``
-             regions emitted by instrumented model code
+  FRAMEWORK  spans around jit/AOT executions, and one host-timed span per
+             layer from the instrumented forward, which runs and
+             synchronizes each layer on its own (``models/predictor.py``)
   SYSTEM     spans/counters derived from the compiled artifact (cost analysis,
-             collective schedule) and host /proc counters
+             collective schedule) and host /proc counters, and the paged
+             serving loop's events (``serve/engine.py``)
+
+No span here reaches the device's own timeline: the model code opens no
+``named_scope``, and a TPU trace's device operations carry no op metadata
+that one could attach to.  Events are timed by the caller's clock.  The
+paged serving loop also opens a ``jax.profiler.TraceAnnotation`` of each
+span's name, so a profiler trace holds its spans on the host line beside
+the device operations.
 
 Events are published asynchronously to a :class:`TracingServer` which merges
 them (by trace id) into one end-to-end timeline — timestamps need not be wall

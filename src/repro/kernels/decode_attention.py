@@ -149,6 +149,7 @@ def decode_attention(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_attention",
     )(
         jnp.asarray(lengths, jnp.int32), wval,
         # q heads are kv-group-major (head = g*rep + r): grouping is a reshape

@@ -160,5 +160,6 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(wval, heads_major(q), heads_major(k), heads_major(v))
     return heads_major(out)

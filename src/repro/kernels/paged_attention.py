@@ -192,6 +192,7 @@ def paged_attention(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_attention",
     )(
         jnp.asarray(page_table, jnp.int32),
         jnp.asarray(lengths, jnp.int32),

@@ -51,5 +51,6 @@ def rmsnorm(
         out_specs=pl.BlockSpec((block_rows, D), lambda ri: (ri, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, D), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, weight)
     return out.reshape(orig_shape)

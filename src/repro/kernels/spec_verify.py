@@ -220,6 +220,7 @@ def spec_verify(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="spec_verify",
     )(
         jnp.asarray(page_table, jnp.int32),
         jnp.asarray(lengths, jnp.int32),

@@ -139,6 +139,7 @@ def ssd(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, A, B, C, s0)
     if return_state:
         return y, sf.astype(x.dtype)

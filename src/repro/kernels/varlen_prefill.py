@@ -281,6 +281,7 @@ def varlen_prefill(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="varlen_prefill",
     )(
         blk_chunk,
         start_blk,

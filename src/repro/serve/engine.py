@@ -46,7 +46,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -123,6 +123,52 @@ def ngram_propose(context: np.ndarray, ngram: int, max_tokens: int) -> List[int]
     return [int(t) for t in cont]
 
 
+class _LoopSpan:
+    """A timed span of the ``serve_paged`` loop, on two clocks.
+
+    A ``jax.profiler.TraceAnnotation`` of the span's name puts it on the
+    profiler's clock, on the host line beside the device operations of a
+    trace; ``t0``/``t1`` are the loop clock's readings at its ends.  With
+    a tracer, ``tags`` is a dict for the caller to fill (it starts with the
+    loop's ``step``), and ``tracer.event(name, t0, t1, **tags)`` publishes
+    the span on the loop's clock as well."""
+
+    __slots__ = ("name", "tags", "t0", "t1", "_tracer", "_clock", "_ann")
+
+    def __init__(self, name: str, tracer, clock: Callable[[], float],
+                 step: int) -> None:
+        self.name = name
+        self.tags = {"step": step} if tracer is not None else None
+        self._tracer = tracer
+        self._clock = clock
+        self._ann = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self) -> _LoopSpan:
+        self._ann.__enter__()
+        self.t0 = self._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = self._clock()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._tracer is not None and exc_type is None:
+            self._tracer.event(self.name, self.t0, self.t1, **self.tags)
+
+
+def _fill_itl(results: List[RequestResult]) -> List[float]:
+    """Set each result's inter-token percentiles from its token times;
+    returns every gap between consecutive tokens of ``results``."""
+    gaps_all: List[float] = []
+    for r in results:
+        t = r.token_times_s
+        gaps = [b - a for a, b in zip(t, t[1:])]
+        if gaps:
+            r.itl_p50_s = percentile(gaps, 50.0)
+            r.itl_p99_s = percentile(gaps, 99.0)
+            gaps_all.extend(gaps)
+    return gaps_all
+
+
 @dataclass
 class GenerationResult:
     tokens: np.ndarray          # (b, new_tokens)
@@ -162,6 +208,12 @@ class RequestResult:
     # so accepted drafts show up as (near-)zero gaps pulling p50 down -------
     itl_p50_s: float = 0.0
     itl_p99_s: float = 0.0
+    # -- per-token timeline (paged engine): submission -> first admission,
+    # and every token emitted on this worker as an offset from submission
+    # (a speculative boundary repeats its time once per token it emits; a
+    # request restored from a snapshot holds only the tokens emitted here)
+    queue_s: float = 0.0
+    token_times_s: List[float] = field(default_factory=list)
     # -- speculative-decoding ledger (0s when spec_k == 0) ------------------
     draft_proposed: int = 0
     draft_accepted: int = 0
@@ -233,6 +285,11 @@ class PagedStats:
     spec_stats: Dict[str, float] = field(default_factory=dict)  # SpecLedger
     itl_p50_ms: float = 0.0     # inter-token latency over every gap in the run
     itl_p99_ms: float = 0.0
+    # -- host loop: iterations of the serving loop, and their wall time
+    # less the waits for the device (packed prefill's ``prefill:wait``,
+    # each decode step's ``decode:fetch``), summed over iterations
+    boundaries: int = 0
+    host_s: float = 0.0
     # -- tensor parallelism -------------------------------------------------
     tp: int = 1                 # effective model-axis degree (1 = unsharded)
     # -- quantized KV pages -------------------------------------------------
@@ -314,15 +371,15 @@ class ServingEngine:
         # Dirty counts are pow2-bucketed (padded with repeats of the last
         # dirty slot) so the scatter compiles log2(num_slots) variants, not
         # one per distinct count; the bucket set is compile-accounted
-        self._mirror_patch = jax.jit(
-            lambda table, pos, nxt, mask, idx, rows, p, n, m: (
+        def mirror_patch(table, pos, nxt, mask, idx, rows, p, n, m):
+            return (
                 table.at[idx].set(rows),
                 pos.at[idx].set(p),
                 nxt.at[idx].set(n),
                 mask.at[idx].set(m),
-            ),
-            donate_argnums=(0, 1, 2, 3),
-        )
+            )
+
+        self._mirror_patch = jax.jit(mirror_patch, donate_argnums=(0, 1, 2, 3))
         self._mirror_patch_shapes: set = set()
         # copy-on-write page duplication (prefix caching): one donated
         # gather/scatter over the pools per shared page about to be written
@@ -354,11 +411,13 @@ class ServingEngine:
         jit traces the wrapped body on first call, so entering the context
         inside the wrapper is what makes ``shard_act`` constraints and the
         serving kernels' shard_map head splits visible to GSPMD.  Identity
-        when the engine has no rules (single-device)."""
+        when the engine has no rules (single-device); the wrapper keeps
+        ``fn``'s name, which names the compiled program."""
         if self.rules is None:
             return fn
         rules = self.rules
 
+        @wraps(fn)
         def wrapped(*args, **kwargs):
             with set_activation_rules(rules):
                 return fn(*args, **kwargs)
@@ -657,7 +716,7 @@ class ServingEngine:
         fn = self._paged_decode_fns.get(pages_bound)
         if fn is None:
 
-            def step(params, nxt, cache, table, pos, mask):
+            def paged_decode_step(params, nxt, cache, table, pos, mask):
                 logits, cache = self.model.decode_paged(
                     params, nxt, cache, table, pos, pages_bound=pages_bound
                 )
@@ -666,7 +725,8 @@ class ServingEngine:
                 new_pos = jnp.where(mask, pos + 1, pos)
                 return tok, new_nxt, new_pos, cache
 
-            fn = jax.jit(self._ruled(step), donate_argnums=(1, 2, 4))
+            fn = jax.jit(self._ruled(paged_decode_step),
+                         donate_argnums=(1, 2, 4))
             self._paged_decode_fns[pages_bound] = fn
         return fn
 
@@ -687,7 +747,7 @@ class ServingEngine:
         fn = self._spec_decode_fns.get(key)
         if fn is None:
 
-            def step(params, win, cache, table, pos, wlens, nxt):
+            def spec_verify_step(params, win, cache, table, pos, wlens, nxt):
                 logits, cache = self.model.decode_spec(
                     params, win, cache, table, pos, wlens,
                     pages_bound=pages_bound,
@@ -717,7 +777,8 @@ class ServingEngine:
                 new_nxt = jnp.where(active, last[:, 0], nxt)
                 return greedy, n_accept, new_pos, new_nxt, cache
 
-            fn = jax.jit(self._ruled(step), donate_argnums=(2, 4, 6))
+            fn = jax.jit(self._ruled(spec_verify_step),
+                         donate_argnums=(2, 4, 6))
             self._spec_decode_fns[key] = fn
         return fn
 
@@ -730,10 +791,13 @@ class ServingEngine:
         key = (chunk_len, pos0)
         fn = self._paged_prefill_fns.get(key)
         if fn is None:
-            fn = jax.jit(
-                self._ruled(partial(self.model.prefill_paged_chunk, pos0=pos0)),
-                donate_argnums=(2,),
-            )
+
+            def paged_prefill_chunk(params, chunk, cache, table_row, last):
+                return self.model.prefill_paged_chunk(
+                    params, chunk, cache, table_row, last, pos0=pos0
+                )
+
+            fn = jax.jit(self._ruled(paged_prefill_chunk), donate_argnums=(2,))
             self._paged_prefill_fns[key] = fn
         return fn
 
@@ -748,12 +812,13 @@ class ServingEngine:
         key = (t_pack, num_chunks, max_pages, pages_bound)
         fn = self._packed_prefill_fns.get(key)
         if fn is None:
-            fn = jax.jit(
-                self._ruled(
-                    partial(self.model.prefill_packed, pages_bound=pages_bound)
-                ),
-                donate_argnums=(2,),
-            )
+
+            def packed_prefill_step(params, batch, cache):
+                return self.model.prefill_packed(
+                    params, batch, cache, pages_bound=pages_bound
+                )
+
+            fn = jax.jit(self._ruled(packed_prefill_step), donate_argnums=(2,))
             self._packed_prefill_fns[key] = fn
         return fn
 
@@ -1034,7 +1099,9 @@ class ServingEngine:
         decode_s = 0.0
         spec = spec_k > 0
         ledger = SpecLedger() if spec else None
-        itl_all: List[float] = []                    # every inter-token gap
+        admitted_at: Dict[int, float] = {}           # request -> first admission
+        boundaries = 0
+        host_s = 0.0
         # -- device-resident decode state: the page table, per-slot positions
         # and (non-spec) next tokens / active mask live on device and are
         # patched only for slots that changed (admission, page growth,
@@ -1087,10 +1154,19 @@ class ServingEngine:
                 moved_bytes=int(payload * count * factor),
             )
 
-        def sync_device(active: List[int]) -> None:
+        def _span(name: str, timed: bool = False):
+            """A span of this loop (``_LoopSpan``); without a tracer, and
+            unless the loop needs its times, only the profiler's
+            annotation."""
+            if tracer is None and not timed:
+                return jax.profiler.TraceAnnotation(name)
+            return _LoopSpan(name, tracer, clock, step)
+
+        def sync_device(active: List[int]) -> int:
             """Patch the device mirrors for slots whose table row, position,
             next token or active-mask bit changed since the last launch —
-            one jitted donated scatter over exactly the dirty slots."""
+            one jitted donated scatter over exactly the dirty slots.
+            Returns how many slots were patched."""
             nonlocal dev_table, dev_pos, dev_nxt, dev_mask, cur_mask
             new_mask = np.zeros((num_slots,), bool)
             new_mask[active] = True
@@ -1120,6 +1196,7 @@ class ServingEngine:
                 )
                 cur_mask = new_mask
                 dirty.clear()
+            return len(stale)
 
         def unpin(slot: int, page: int) -> None:
             """Drop ``slot``'s record of mapping ``page`` from the cache (the
@@ -1370,6 +1447,8 @@ class ServingEngine:
             return best
 
         while queue or slots.num_active:
+            t_boundary = clock()
+            waited = 0.0        # inside prefill:wait and decode:fetch
             progressed = False
             # 0a) periodic checkpoint: runs BEFORE the fault hook, so a
             #     crash at boundary S observes checkpoints as-of S — the
@@ -1410,6 +1489,7 @@ class ServingEngine:
                         finished[r.request_id] for r in requests
                         if r.request_id in finished
                     ]
+                    _fill_itl(crash.results)
                     crash.pending = [
                         r for r in requests if r.request_id not in finished
                     ]
@@ -1417,32 +1497,32 @@ class ServingEngine:
                         fault_hook.release()   # return seized pressure pages
                     raise
             # 1) retire finished sequences, returning their pages
-            for slot in list(decoding):
-                req = slots.active[slot]
-                if len(slot_tokens[slot]) >= req.max_new_tokens:
+            with _span("sched:retire") as sp:
+                retired = 0
+                for slot in list(decoding):
+                    req = slots.active[slot]
+                    if len(slot_tokens[slot]) < req.max_new_tokens:
+                        continue
                     now = clock()
-                    itls = [
-                        b - a for a, b in zip(
-                            slot_times.get(slot, []), slot_times.get(slot, [])[1:]
-                        )
-                    ]
-                    itl_all.extend(itls)
-                    prop, acc = ledger.of(req.request_id) if ledger else (0, 0)
-                    latency = now - submit_s[req.request_id]
-                    finished[req.request_id] = RequestResult(
-                        request_id=req.request_id,
+                    rid = req.request_id
+                    t_sub, t_adm = submit_s[rid], admitted_at[rid]
+                    t_first = req._first_at          # type: ignore[attr-defined]
+                    prop, acc = ledger.of(rid) if ledger else (0, 0)
+                    latency = now - t_sub
+                    finished[rid] = RequestResult(
+                        request_id=rid,
                         tokens=np.asarray(slot_tokens[slot], np.int32),
                         slot=slot,
                         admit_step=req._admit_step,  # type: ignore[attr-defined]
                         finish_step=step,
-                        ttft_s=req._ttft_s,          # type: ignore[attr-defined]
+                        ttft_s=t_first - t_sub,
                         latency_s=latency,
                         tokens_per_s=(
                             req.max_new_tokens / latency
-                            if now > submit_s[req.request_id] else float("inf")
+                            if now > t_sub else float("inf")
                         ),
-                        itl_p50_s=percentile(itls, 50.0) if itls else 0.0,
-                        itl_p99_s=percentile(itls, 99.0) if itls else 0.0,
+                        queue_s=t_adm - t_sub,
+                        token_times_s=[t - t_sub for t in slot_times[slot]],
                         draft_proposed=prop,
                         draft_accepted=acc,
                         tenant=getattr(req, "tenant", "default"),
@@ -1451,9 +1531,18 @@ class ServingEngine:
                         # goodput — the fleet's within_deadline semantics
                         within_deadline=deadline is None or now <= deadline,
                     )
+                    if tracer is not None:
+                        # one request's phases, tiling submission to finish
+                        tracer.event("request:queued", t_sub, t_adm, request=rid)
+                        tracer.event("request:prefill", t_adm, t_first,
+                                     request=rid)
+                        tracer.event("request:decode", t_first, now, request=rid)
                     emit_tenant(req, "completed", now, latency)
                     release_slot(slot)
+                    retired += 1
                     progressed = True
+                if tracer is not None:
+                    sp.tags["retired"] = retired
             # 2) admission keyed on free pages: a request enters only when a
             #    slot AND its prompt's pages are available AND its worst-case
             #    page commitment fits the (possibly overcommitted) pool.
@@ -1463,342 +1552,366 @@ class ServingEngine:
             #    counts each shared page ONCE globally (plus one COW page
             #    for a full hit), and cached-unreferenced pages are evicted
             #    on demand before admission gives up
-            if deadline is not None and queue and clock() > deadline:
-                # TTL passed while still queued: terminal rejected (fleet
-                # parity) — expired work leaves the queue, it never runs
-                while queue:
-                    reject(queue.popleft(), "deadline")
-                progressed = True
-            while queue:
-                now_adm = clock()
-                idx0 = pick_admission(now_adm)
-                req0 = queue[idx0]
-                if unmeetable(
-                    req0,
-                    sum(len(r.prompt) for r in queue) - len(req0.prompt),
-                    now_adm,
-                ):
-                    del queue[idx0]
-                    reject(req0, "slo-unmeetable")
+            with _span("sched:admit") as sp:
+                admitted = 0
+                if deadline is not None and queue and clock() > deadline:
+                    # TTL passed while still queued: terminal rejected (fleet
+                    # parity) — expired work leaves the queue, it never runs
+                    while queue:
+                        reject(queue.popleft(), "deadline")
                     progressed = True
-                    continue
-                # migrate-restore admission: a request arriving with a
-                # checkpointed snapshot skips prefill entirely — verify the
-                # per-page checksums, scatter the snapshot into freshly
-                # allocated pages, rebuild lengths + emitted tokens, and
-                # continue decoding bit-identically.  A failed verify drops
-                # the snapshot and falls through to ordinary prefill
-                # (replay-from-prompt): corrupted state is never served.
-                snap = restores.get(req0.request_id) if restores else None
-                if snap is not None and not snap.verify():
-                    checksum_failures += 1
-                    del restores[req0.request_id]
-                    if tracer is not None:
-                        now_cf = clock()
-                        tracer.event(
-                            "migrate:checksum_fail", now_cf, now_cf,
-                            request=req0.request_id, step=step,
-                            pages=snap.num_pages,
+                while queue:
+                    now_adm = clock()
+                    idx0 = pick_admission(now_adm)
+                    req0 = queue[idx0]
+                    if unmeetable(
+                        req0,
+                        sum(len(r.prompt) for r in queue) - len(req0.prompt),
+                        now_adm,
+                    ):
+                        del queue[idx0]
+                        reject(req0, "slo-unmeetable")
+                        progressed = True
+                        continue
+                    # migrate-restore admission: a request arriving with a
+                    # checkpointed snapshot skips prefill entirely — verify the
+                    # per-page checksums, scatter the snapshot into freshly
+                    # allocated pages, rebuild lengths + emitted tokens, and
+                    # continue decoding bit-identically.  A failed verify drops
+                    # the snapshot and falls through to ordinary prefill
+                    # (replay-from-prompt): corrupted state is never served.
+                    snap = restores.get(req0.request_id) if restores else None
+                    if snap is not None and not snap.verify():
+                        checksum_failures += 1
+                        del restores[req0.request_id]
+                        if tracer is not None:
+                            now_cf = clock()
+                            tracer.event(
+                                "migrate:checksum_fail", now_cf, now_cf,
+                                request=req0.request_id, step=step,
+                                pages=snap.num_pages,
+                            )
+                        snap = None
+                    if snap is not None:
+                        worst = pool.pages_needed(
+                            len(req0.prompt) + req0.max_new_tokens
                         )
-                    snap = None
-                if snap is not None:
-                    worst = pool.pages_needed(
-                        len(req0.prompt) + req0.max_new_tokens
+                        npages = snap.num_pages
+                        committed = sum(slot_commit.values()) + len(pinned_refs)
+                        if not slots.num_free:
+                            break
+                        if committed + worst > pool.capacity * overcommit:
+                            break
+                        if not ensure_free(npages):
+                            break
+                        req = req0
+                        del queue[idx0]
+                        del restores[req.request_id]
+                        if fair:
+                            tenant_ledger.on_admit(
+                                getattr(req, "tenant", "default"), req_cost(req),
+                                now_adm,
+                            )
+                        t0m = clock()
+                        slot, pages = slots.admit_paged(req, npages, step=step)
+                        admitted_at.setdefault(req.request_id, now_adm)
+                        table.assign(slot, pages)
+                        # scatter the snapshot into the fresh pages: destination
+                        # AND source are padded to the pow2 bucket with the last
+                        # real page (duplicate scatter indices rewrite the same
+                        # bytes, so the import is idempotent)
+                        cnt = bucket_pow2(len(pages), cap=max_pages_per_seq)
+                        self._xfer_shapes.add((num_pages, page_size, cnt))
+                        dst = np.fromiter(pages, np.int32, len(pages))
+                        dst = np.concatenate(
+                            [dst, np.full((cnt - len(pages),), dst[-1], np.int32)]
+                        )
+                        sel = np.concatenate([
+                            np.arange(len(pages), dtype=np.int32),
+                            np.full((cnt - len(pages),), len(pages) - 1, np.int32),
+                        ])
+                        if "k_scales" in cache:
+                            (cache["k_pages"], cache["v_pages"],
+                             cache["k_scales"], cache["v_scales"]) = self._import_q(
+                                cache["k_pages"], cache["v_pages"], dst,
+                                jnp.asarray(snap.k[:, sel]),
+                                jnp.asarray(snap.v[:, sel]),
+                                cache["k_scales"], cache["v_scales"],
+                                jnp.asarray(snap.k_scales[:, sel]),
+                                jnp.asarray(snap.v_scales[:, sel]),
+                            )
+                        else:
+                            cache["k_pages"], cache["v_pages"] = self._import(
+                                cache["k_pages"], cache["v_pages"], dst,
+                                jnp.asarray(snap.k[:, sel]),
+                                jnp.asarray(snap.v[:, sel]),
+                            )
+                        lengths[slot] = snap.length
+                        toks = [int(t) for t in snap.tokens]
+                        slot_tokens[slot] = toks
+                        slot_times[slot] = []
+                        nxt[slot] = toks[-1]
+                        slot_commit[slot] = worst
+                        slot_cached[slot] = 0
+                        slot_prefilled[slot] = 0
+                        admit_order[slot] = admit_seq
+                        admit_seq += 1
+                        req._admit_step = step      # type: ignore[attr-defined]
+                        # first token was emitted on the source worker; TTFT on
+                        # the survivor is the restore latency itself
+                        req._first_at = clock()     # type: ignore[attr-defined]
+                        decoding.add(slot)
+                        restored_slots.add(slot)
+                        dirty.add(slot)
+                        restored_n += 1
+                        restored_tok += snap.length
+                        restore_bytes += snap.nbytes
+                        if tracer is not None:
+                            tracer.event(
+                                "migrate:restore", t0m, clock(),
+                                request=req.request_id, pages=len(pages),
+                                bytes=snap.nbytes, tokens=len(toks),
+                                length=snap.length,
+                            )
+                        admitted += 1
+                        progressed = True
+                        continue
+                    hit_pages: List[int] = []
+                    cached = 0
+                    if pcache is not None:
+                        hit_pages, cached = pcache.match(req0.prompt)
+                    full_hit = cached >= len(req0.prompt)
+                    npages = pool.pages_needed(len(req0.prompt)) - len(hit_pages)
+                    worst = pool.pages_needed(len(req0.prompt) + req0.max_new_tokens)
+                    # private worst case: shared pages are not this request's
+                    # cost (they're pinned once, below); a full hit will split
+                    # its shared last page copy-on-write, so reserve that page
+                    commit = worst - len(hit_pages) + (1 if full_hit else 0)
+                    # shared pages counted once globally: every page some slot
+                    # already mapped from the cache plus the ones THIS admission
+                    # would newly pin
+                    pinned = len(pinned_refs) + sum(
+                        1 for p in hit_pages if p not in pinned_refs
                     )
-                    npages = snap.num_pages
-                    committed = sum(slot_commit.values()) + len(pinned_refs)
+                    committed = sum(slot_commit.values()) + pinned
                     if not slots.num_free:
                         break
-                    if committed + worst > pool.capacity * overcommit:
+                    if committed + commit > pool.capacity * overcommit:
                         break
+                    # pin the hit pages BEFORE eviction runs: they are exactly
+                    # the cached-unreferenced pages ensure_free may reclaim
+                    if hit_pages:
+                        pool.incref(hit_pages)
                     if not ensure_free(npages):
+                        if hit_pages:
+                            pool.free(hit_pages)
                         break
                     req = req0
                     del queue[idx0]
-                    del restores[req.request_id]
                     if fair:
                         tenant_ledger.on_admit(
                             getattr(req, "tenant", "default"), req_cost(req),
                             now_adm,
                         )
-                    t0m = clock()
+                    if pcache is not None:
+                        pcache.record(len(req.prompt), hit_pages)
                     slot, pages = slots.admit_paged(req, npages, step=step)
-                    table.assign(slot, pages)
-                    # scatter the snapshot into the fresh pages: destination
-                    # AND source are padded to the pow2 bucket with the last
-                    # real page (duplicate scatter indices rewrite the same
-                    # bytes, so the import is idempotent)
-                    cnt = bucket_pow2(len(pages), cap=max_pages_per_seq)
-                    self._xfer_shapes.add((num_pages, page_size, cnt))
-                    dst = np.fromiter(pages, np.int32, len(pages))
-                    dst = np.concatenate(
-                        [dst, np.full((cnt - len(pages),), dst[-1], np.int32)]
-                    )
-                    sel = np.concatenate([
-                        np.arange(len(pages), dtype=np.int32),
-                        np.full((cnt - len(pages),), len(pages) - 1, np.int32),
-                    ])
-                    if "k_scales" in cache:
-                        (cache["k_pages"], cache["v_pages"],
-                         cache["k_scales"], cache["v_scales"]) = self._import_q(
-                            cache["k_pages"], cache["v_pages"], dst,
-                            jnp.asarray(snap.k[:, sel]),
-                            jnp.asarray(snap.v[:, sel]),
-                            cache["k_scales"], cache["v_scales"],
-                            jnp.asarray(snap.k_scales[:, sel]),
-                            jnp.asarray(snap.v_scales[:, sel]),
-                        )
-                    else:
-                        cache["k_pages"], cache["v_pages"] = self._import(
-                            cache["k_pages"], cache["v_pages"], dst,
-                            jnp.asarray(snap.k[:, sel]),
-                            jnp.asarray(snap.v[:, sel]),
-                        )
-                    lengths[slot] = snap.length
-                    toks = [int(t) for t in snap.tokens]
-                    slot_tokens[slot] = toks
-                    slot_times[slot] = []
-                    nxt[slot] = toks[-1]
-                    slot_commit[slot] = worst
-                    slot_cached[slot] = 0
+                    admitted_at.setdefault(req.request_id, now_adm)
+                    table.assign(slot, hit_pages + pages)
+                    for p in hit_pages:
+                        pinned_refs[p] = pinned_refs.get(p, 0) + 1
+                    slot_shared[slot] = list(hit_pages)
+                    slot_tokens[slot] = []
+                    slot_commit[slot] = commit
                     slot_prefilled[slot] = 0
+                    prompt_admitted += len(req.prompt)
                     admit_order[slot] = admit_seq
                     admit_seq += 1
-                    req._admit_step = step      # type: ignore[attr-defined]
-                    # first token was emitted on the source worker; TTFT on
-                    # the survivor is the restore latency itself
-                    req._ttft_s = clock() - submit_s[req.request_id]  # type: ignore
-                    decoding.add(slot)
-                    restored_slots.add(slot)
-                    dirty.add(slot)
-                    restored_n += 1
-                    restored_tok += snap.length
-                    restore_bytes += snap.nbytes
-                    if tracer is not None:
+                    req._admit_step = step              # type: ignore[attr-defined]
+                    if full_hit:
+                        # every prompt page is cached: skip prefill entirely and
+                        # replay the last prompt token through the decode path
+                        # (its append copy-on-writes the shared last page); TTFT
+                        # collapses to one decode boundary
+                        slot_cached[slot] = len(req.prompt)
+                        saved_tokens += len(req.prompt)
+                        if budget is not None:
+                            budget.credit(len(req.prompt))
+                        lengths[slot] = len(req.prompt) - 1
+                        nxt[slot] = int(req.prompt[-1])
+                        slot_times[slot] = []
+                        decoding.add(slot)
+                        replay_first.add(slot)
+                        dirty.add(slot)
+                    else:
+                        slot_cached[slot] = cached
+                        saved_tokens += cached
+                        if budget is not None and cached:
+                            budget.credit(cached)
+                        lengths[slot] = cached
+                        prefilling[slot] = cached
+                    if tracer is not None and pcache is not None:
+                        now = clock()
                         tracer.event(
-                            "migrate:restore", t0m, clock(),
-                            request=req.request_id, pages=len(pages),
-                            bytes=snap.nbytes, tokens=len(toks),
-                            length=snap.length,
+                            "prefix:lookup", now, now,
+                            prompt_tokens=len(req.prompt), cached_tokens=cached,
+                            hit_pages=len(hit_pages), full_hit=int(full_hit),
                         )
+                    admitted += 1
                     progressed = True
-                    continue
-                hit_pages: List[int] = []
-                cached = 0
-                if pcache is not None:
-                    hit_pages, cached = pcache.match(req0.prompt)
-                full_hit = cached >= len(req0.prompt)
-                npages = pool.pages_needed(len(req0.prompt)) - len(hit_pages)
-                worst = pool.pages_needed(len(req0.prompt) + req0.max_new_tokens)
-                # private worst case: shared pages are not this request's
-                # cost (they're pinned once, below); a full hit will split
-                # its shared last page copy-on-write, so reserve that page
-                commit = worst - len(hit_pages) + (1 if full_hit else 0)
-                # shared pages counted once globally: every page some slot
-                # already mapped from the cache plus the ones THIS admission
-                # would newly pin
-                pinned = len(pinned_refs) + sum(
-                    1 for p in hit_pages if p not in pinned_refs
-                )
-                committed = sum(slot_commit.values()) + pinned
-                if not slots.num_free:
-                    break
-                if committed + commit > pool.capacity * overcommit:
-                    break
-                # pin the hit pages BEFORE eviction runs: they are exactly
-                # the cached-unreferenced pages ensure_free may reclaim
-                if hit_pages:
-                    pool.incref(hit_pages)
-                if not ensure_free(npages):
-                    if hit_pages:
-                        pool.free(hit_pages)
-                    break
-                req = req0
-                del queue[idx0]
-                if fair:
-                    tenant_ledger.on_admit(
-                        getattr(req, "tenant", "default"), req_cost(req),
-                        now_adm,
-                    )
-                if pcache is not None:
-                    pcache.record(len(req.prompt), hit_pages)
-                slot, pages = slots.admit_paged(req, npages, step=step)
-                table.assign(slot, hit_pages + pages)
-                for p in hit_pages:
-                    pinned_refs[p] = pinned_refs.get(p, 0) + 1
-                slot_shared[slot] = list(hit_pages)
-                slot_tokens[slot] = []
-                slot_commit[slot] = commit
-                slot_prefilled[slot] = 0
-                prompt_admitted += len(req.prompt)
-                admit_order[slot] = admit_seq
-                admit_seq += 1
-                req._admit_step = step              # type: ignore[attr-defined]
-                if full_hit:
-                    # every prompt page is cached: skip prefill entirely and
-                    # replay the last prompt token through the decode path
-                    # (its append copy-on-writes the shared last page); TTFT
-                    # collapses to one decode boundary
-                    slot_cached[slot] = len(req.prompt)
-                    saved_tokens += len(req.prompt)
-                    if budget is not None:
-                        budget.credit(len(req.prompt))
-                    lengths[slot] = len(req.prompt) - 1
-                    nxt[slot] = int(req.prompt[-1])
-                    slot_times[slot] = []
-                    decoding.add(slot)
-                    replay_first.add(slot)
-                    dirty.add(slot)
-                else:
-                    slot_cached[slot] = cached
-                    saved_tokens += cached
-                    if budget is not None and cached:
-                        budget.credit(cached)
-                    lengths[slot] = cached
-                    prefilling[slot] = cached
-                if tracer is not None and pcache is not None:
-                    now = clock()
-                    tracer.event(
-                        "prefix:lookup", now, now,
-                        prompt_tokens=len(req.prompt), cached_tokens=cached,
-                        hit_pages=len(hit_pages), full_hit=int(full_hit),
-                    )
-                progressed = True
-            if fair and queue:
-                # tenants whose arrived work was passed over because their
-                # bucket ran dry: one deferral per tenant per boundary
-                now_d = clock()
-                seen_dry: set = set()
-                for r in queue:
-                    tname = getattr(r, "tenant", "default")
-                    if tname not in seen_dry and tenant_ledger.dry(
-                            tname, req_cost(r), now_d):
-                        seen_dry.add(tname)
-                        tenant_ledger.note_defer(tname)
-                        deferred_n += 1
-                        if tracer is not None:
-                            tracer.event("sched:defer", now_d, now_d,
-                                         tenant=tname)
+                if fair and queue:
+                    # tenants whose arrived work was passed over because their
+                    # bucket ran dry: one deferral per tenant per boundary
+                    now_d = clock()
+                    seen_dry: set = set()
+                    for r in queue:
+                        tname = getattr(r, "tenant", "default")
+                        if tname not in seen_dry and tenant_ledger.dry(
+                                tname, req_cost(r), now_d):
+                            seen_dry.add(tname)
+                            tenant_ledger.note_defer(tname)
+                            deferred_n += 1
+                            if tracer is not None:
+                                tracer.event("sched:defer", now_d, now_d,
+                                             tenant=tname)
+                if tracer is not None:
+                    sp.tags.update(admitted=admitted, queued=len(queue))
             # 3) prefill at the boundary, interleaved with decode.
             #    packed: coalesce every prefilling slot's next span into ONE
             #    token-packed varlen launch (oldest first, capped by the
             #    per-boundary token budget); chunked: one batch-1 chunk per
             #    slot (legacy path, one jit variant per length × offset)
             if prefilling and packed:
-                t0p = clock()
-                budget.begin_step()
-                spans: List[Tuple[int, int, int, int]] = []
-                used = 0
-                for slot in sorted(prefilling, key=lambda s: admit_order[s]):
-                    req = slots.active[slot]
-                    rem = len(req.prompt) - prefilling[slot]
-                    if used >= t_pack:
-                        budget.defer(rem)   # left waiting: starvation signal
-                        continue
-                    # the buffer cap (padded spans) is never looser than the
-                    # ledger (real tokens), so grants keep spans page-aligned
-                    take = budget.grant(min(rem, t_pack - used))
-                    if take <= 0:
-                        budget.defer(rem)
-                        continue
-                    if take < rem:
-                        budget.defer(rem - take)
-                    span = pages_needed(take, page_size) * page_size
-                    spans.append((slot, prefilling[slot], take, span))
-                    used += span
-                if spans:
-                    num_chunks = num_slots
-                    tokens_p = np.zeros((1, t_pack), np.int32)
-                    tok_pos = np.zeros((t_pack,), np.int32)
-                    # buffer-tail pads scatter their K/V into the scratch
-                    # page; offsets cycle so writes spread over its rows
-                    dst_page = np.zeros((t_pack,), np.int32)
-                    dst_off = (np.arange(t_pack) % page_size).astype(np.int32)
-                    cu = np.zeros((num_chunks + 1,), np.int32)
-                    lens_c = np.zeros((num_chunks,), np.int32)
-                    pos0_c = np.zeros((num_chunks,), np.int32)
-                    last_idx = np.zeros((num_chunks,), np.int32)
-                    tables_c = np.zeros((num_chunks, max_pages_per_seq), np.int32)
-                    off = 0
-                    for ci, (slot, start, take, span) in enumerate(spans):
-                        req = slots.active[slot]
-                        tokens_p[0, off : off + take] = req.prompt[
-                            start : start + take
-                        ]
-                        pos = start + np.arange(span, dtype=np.int32)
-                        tok_pos[off : off + span] = pos
-                        row = table.table[slot]
-                        # chunk-pad K/V lands inside the prompt's already-
-                        # allocated pages (length-masked until overwritten),
-                        # exactly like the chunked path's padded tail
-                        dst_page[off : off + span] = row[pos // page_size]
-                        dst_off[off : off + span] = pos % page_size
-                        cu[ci + 1] = off + span
-                        lens_c[ci] = take
-                        pos0_c[ci] = start
-                        last_idx[ci] = off + take - 1
-                        tables_c[ci] = row
-                        off += span
-                    cu[len(spans) + 1 :] = off
-                    # static bound on committed-context pages this launch,
-                    # pow2-bucketed so early (low-context) launches don't
-                    # stream/gather the full page-table width
-                    ctx_pages = max(
-                        pages_needed(start, page_size)
-                        for _, start, _, _ in spans
-                    )
-                    bound = bucket_pow2(max(ctx_pages, 1),
-                                        cap=max_pages_per_seq)
-                    fn = self._packed_prefill_fn(
-                        t_pack, num_chunks, max_pages_per_seq, bound
-                    )
-                    batch_p = {
-                        "tokens": jnp.asarray(tokens_p),
-                        "tok_pos": jnp.asarray(tok_pos),
-                        "dst_page": jnp.asarray(dst_page),
-                        "dst_off": jnp.asarray(dst_off),
-                        "cu_seqlens": jnp.asarray(cu),
-                        "chunk_lens": jnp.asarray(lens_c),
-                        "chunk_pos0": jnp.asarray(pos0_c),
-                        "page_tables": jnp.asarray(tables_c),
-                        "last_idx": jnp.asarray(last_idx),
-                    }
-                    logits, cache = fn(self.params, batch_p, cache)
-                    jax.block_until_ready(logits)
-                    for ci, (slot, start, take, span) in enumerate(spans):
-                        req = slots.active[slot]
-                        new_start = start + take
-                        lengths[slot] = new_start
-                        slot_prefilled[slot] = slot_prefilled.get(slot, 0) + take
-                        chunks_done += 1
-                        if new_start >= len(req.prompt):
-                            del prefilling[slot]
-                            if pcache is not None:
-                                pcache.insert(req.prompt, table.pages_of(slot))
-                            tok0 = int(jnp.argmax(logits[ci]))
-                            nxt[slot] = tok0
-                            slot_tokens[slot] = [tok0]
-                            decoding.add(slot)
-                            dirty.add(slot)
-                            tnow = clock()
-                            slot_times[slot] = [tnow]
-                            req._ttft_s = tnow - submit_s[req.request_id]  # type: ignore
-                        else:
-                            prefilling[slot] = new_start
-                    real = sum(s[2] for s in spans)
-                    prefill_launches += 1
-                    prefill_tokens += real
-                    prefill_padded += t_pack - real
-                    now = clock()
-                    prefill_s += now - t0p
+                with _span("prefill:packed", timed=True) as sp:
                     if tracer is not None:
-                        tracer.event(
-                            "prefill:packed", t0p, now,
-                            tokens=real, padding=t_pack - real,
-                            chunks=len(spans), buffer=t_pack,
-                            budget=budget.tokens_per_step,
+                        # requests already decoding, held up by this launch
+                        sp.tags["decoding"] = sum(
+                            1 for s in decoding
+                            if 0 < len(slot_tokens[s])
+                            < slots.active[s].max_new_tokens
                         )
-                    tp_event("prefill", t0p, now, t_pack, seq_shardable=True)
+                    budget.begin_step()
+                    spans: List[Tuple[int, int, int, int]] = []
+                    used = 0
+                    for slot in sorted(prefilling, key=lambda s: admit_order[s]):
+                        req = slots.active[slot]
+                        rem = len(req.prompt) - prefilling[slot]
+                        if used >= t_pack:
+                            budget.defer(rem)   # left waiting: starvation signal
+                            continue
+                        # the buffer cap (padded spans) is never looser than the
+                        # ledger (real tokens), so grants keep spans page-aligned
+                        take = budget.grant(min(rem, t_pack - used))
+                        if take <= 0:
+                            budget.defer(rem)
+                            continue
+                        if take < rem:
+                            budget.defer(rem - take)
+                        span = pages_needed(take, page_size) * page_size
+                        spans.append((slot, prefilling[slot], take, span))
+                        used += span
+                    if spans:
+                        num_chunks = num_slots
+                        tokens_p = np.zeros((1, t_pack), np.int32)
+                        tok_pos = np.zeros((t_pack,), np.int32)
+                        # buffer-tail pads scatter their K/V into the scratch
+                        # page; offsets cycle so writes spread over its rows
+                        dst_page = np.zeros((t_pack,), np.int32)
+                        dst_off = (np.arange(t_pack) % page_size).astype(np.int32)
+                        cu = np.zeros((num_chunks + 1,), np.int32)
+                        lens_c = np.zeros((num_chunks,), np.int32)
+                        pos0_c = np.zeros((num_chunks,), np.int32)
+                        last_idx = np.zeros((num_chunks,), np.int32)
+                        tables_c = np.zeros((num_chunks, max_pages_per_seq), np.int32)
+                        off = 0
+                        for ci, (slot, start, take, span) in enumerate(spans):
+                            req = slots.active[slot]
+                            tokens_p[0, off : off + take] = req.prompt[
+                                start : start + take
+                            ]
+                            pos = start + np.arange(span, dtype=np.int32)
+                            tok_pos[off : off + span] = pos
+                            row = table.table[slot]
+                            # chunk-pad K/V lands inside the prompt's already-
+                            # allocated pages (length-masked until overwritten),
+                            # exactly like the chunked path's padded tail
+                            dst_page[off : off + span] = row[pos // page_size]
+                            dst_off[off : off + span] = pos % page_size
+                            cu[ci + 1] = off + span
+                            lens_c[ci] = take
+                            pos0_c[ci] = start
+                            last_idx[ci] = off + take - 1
+                            tables_c[ci] = row
+                            off += span
+                        cu[len(spans) + 1 :] = off
+                        # static bound on committed-context pages this launch,
+                        # pow2-bucketed so early (low-context) launches don't
+                        # stream/gather the full page-table width
+                        ctx_pages = max(
+                            pages_needed(start, page_size)
+                            for _, start, _, _ in spans
+                        )
+                        bound = bucket_pow2(max(ctx_pages, 1),
+                                            cap=max_pages_per_seq)
+                        fn = self._packed_prefill_fn(
+                            t_pack, num_chunks, max_pages_per_seq, bound
+                        )
+                        batch_p = {
+                            "tokens": jnp.asarray(tokens_p),
+                            "tok_pos": jnp.asarray(tok_pos),
+                            "dst_page": jnp.asarray(dst_page),
+                            "dst_off": jnp.asarray(dst_off),
+                            "cu_seqlens": jnp.asarray(cu),
+                            "chunk_lens": jnp.asarray(lens_c),
+                            "chunk_pos0": jnp.asarray(pos0_c),
+                            "page_tables": jnp.asarray(tables_c),
+                            "last_idx": jnp.asarray(last_idx),
+                        }
+                        logits, cache = fn(self.params, batch_p, cache)
+                        with _span("prefill:wait", timed=True) as sw:
+                            jax.block_until_ready(logits)
+                        waited += sw.t1 - sw.t0
+                        with _span("prefill:first_tokens") as sf:
+                            n_first = 0
+                            for ci, (slot, start, take, span) in enumerate(spans):
+                                req = slots.active[slot]
+                                new_start = start + take
+                                lengths[slot] = new_start
+                                slot_prefilled[slot] = (
+                                    slot_prefilled.get(slot, 0) + take
+                                )
+                                chunks_done += 1
+                                if new_start >= len(req.prompt):
+                                    del prefilling[slot]
+                                    if pcache is not None:
+                                        pcache.insert(req.prompt, table.pages_of(slot))
+                                    tok0 = int(jnp.argmax(logits[ci]))
+                                    n_first += 1
+                                    nxt[slot] = tok0
+                                    slot_tokens[slot] = [tok0]
+                                    decoding.add(slot)
+                                    dirty.add(slot)
+                                    tnow = clock()
+                                    slot_times[slot] = [tnow]
+                                    req._first_at = tnow  # type: ignore
+                                else:
+                                    prefilling[slot] = new_start
+                            if tracer is not None:
+                                sf.tags["n"] = n_first
+                        real = sum(s[2] for s in spans)
+                        prefill_launches += 1
+                        prefill_tokens += real
+                        prefill_padded += t_pack - real
+                        if tracer is not None:
+                            sp.tags.update(
+                                tokens=real, padding=t_pack - real,
+                                chunks=len(spans), buffer=t_pack,
+                                budget=budget.tokens_per_step,
+                            )
+                prefill_s += sp.t1 - sp.t0
+                if spans:
+                    tp_event("prefill", sp.t0, sp.t1, t_pack,
+                             seq_shardable=True)
                     progressed = True
             elif prefilling:
                 t0p = clock()
@@ -1846,7 +1959,7 @@ class ServingEngine:
                         dirty.add(slot)
                         tnow = clock()
                         slot_times[slot] = [tnow]
-                        req._ttft_s = tnow - submit_s[req.request_id]  # type: ignore
+                        req._first_at = tnow    # type: ignore[attr-defined]
                     else:
                         prefilling[slot] = start
                 now = clock()
@@ -1889,68 +2002,83 @@ class ServingEngine:
             # fails, first trim the slot's draft to the pages it already
             # holds — only the REAL next token's page may preempt, exactly
             # like the non-spec path
-            for s in sorted(active_dec, key=lambda s: admit_order[s]):
-                while s in decoding and not cow_if_shared(s):
-                    if preempt_one() is None:
-                        raise RuntimeError(
-                            "page pool exhausted with nothing to preempt"
-                        )
-                while (
-                    s in decoding   # may have been evicted (even by itself)
-                    and table.num_pages_of(s) * page_size
-                    <= int(lengths[s]) + len(drafts.get(s, ()))
-                ):
-                    grown = slots.grow(1) if ensure_free(1) else None
-                    if grown is None:
-                        d = drafts.get(s)
-                        if d:
-                            fit = (table.num_pages_of(s) * page_size
-                                   - int(lengths[s]) - 1)
-                            del d[max(fit, 0):]
-                            continue
-                        if preempt_one() is None:
-                            raise RuntimeError(
-                                "page pool exhausted with nothing to preempt"
-                            )
-                        continue
-                    table.append(s, grown[0])
-                    dirty.add(s)
+            if active_dec:
+                with _span("pages:grow") as sp:
+                    n_grown = 0
+                    for s in sorted(active_dec, key=lambda s: admit_order[s]):
+                        while s in decoding and not cow_if_shared(s):
+                            if preempt_one() is None:
+                                raise RuntimeError(
+                                    "page pool exhausted with nothing to preempt"
+                                )
+                        while (
+                            s in decoding   # may have been evicted (even by itself)
+                            and table.num_pages_of(s) * page_size
+                            <= int(lengths[s]) + len(drafts.get(s, ()))
+                        ):
+                            grown = slots.grow(1) if ensure_free(1) else None
+                            if grown is None:
+                                d = drafts.get(s)
+                                if d:
+                                    fit = (table.num_pages_of(s) * page_size
+                                           - int(lengths[s]) - 1)
+                                    del d[max(fit, 0):]
+                                    continue
+                                if preempt_one() is None:
+                                    raise RuntimeError(
+                                        "page pool exhausted with nothing to preempt"
+                                    )
+                                continue
+                            table.append(s, grown[0])
+                            dirty.add(s)
+                            n_grown += 1
+                    if tracer is not None:
+                        sp.tags["grown"] = n_grown
             active_dec = [s for s in active_dec if s in decoding]  # may be preempted
             if active_dec:
-                t0d = clock()
-                use_spec = spec and any(drafts.get(s) for s in active_dec)
-                W = spec_k + 1 if use_spec else 1
-                sync_device(active_dec)
-                live = max(
-                    int(lengths[s]) + 1 + len(drafts.get(s, ()))
-                    for s in active_dec
-                )
-                bound = bucket_pow2(
-                    pages_needed(live, page_size), cap=max_pages_per_seq
-                )
-                if use_spec:
-                    win = np.zeros((num_slots, W), np.int32)
-                    wlens_h = np.zeros((num_slots,), np.int32)
-                    for s in active_dec:
-                        d = drafts.get(s, [])
-                        win[s, 0] = nxt[s]
-                        win[s, 1 : 1 + len(d)] = d
-                        wlens_h[s] = 1 + len(d)
-                    fn = self._spec_decode_fn(bound, W)
-                    greedy, n_acc, dev_pos, dev_nxt, cache = fn(
-                        self.params, win, cache, dev_table,
-                        dev_pos, wlens_h, dev_nxt,
+                with _span("decode:step", timed=True) as sd:
+                    use_spec = spec and any(drafts.get(s) for s in active_dec)
+                    W = spec_k + 1 if use_spec else 1
+                    with _span("decode:patch") as sp:
+                        patched = sync_device(active_dec)
+                        if tracer is not None:
+                            sp.tags["dirty"] = patched
+                    live = max(
+                        int(lengths[s]) + 1 + len(drafts.get(s, ()))
+                        for s in active_dec
                     )
-                    g, na = jax.device_get((greedy, n_acc))
-                else:
-                    fn = self._paged_decode_fn(bound)
-                    tok, dev_nxt, dev_pos, cache = fn(
-                        self.params, dev_nxt, cache, dev_table, dev_pos,
-                        dev_mask,
+                    bound = bucket_pow2(
+                        pages_needed(live, page_size), cap=max_pages_per_seq
                     )
-                    g = np.asarray(tok)[:, None]
-                    na = np.zeros((num_slots,), np.int32)
-                now = clock()
+                    if use_spec:
+                        win = np.zeros((num_slots, W), np.int32)
+                        wlens_h = np.zeros((num_slots,), np.int32)
+                        for s in active_dec:
+                            d = drafts.get(s, [])
+                            win[s, 0] = nxt[s]
+                            win[s, 1 : 1 + len(d)] = d
+                            wlens_h[s] = 1 + len(d)
+                        fn = self._spec_decode_fn(bound, W)
+                        greedy, n_acc, dev_pos, dev_nxt, cache = fn(
+                            self.params, win, cache, dev_table,
+                            dev_pos, wlens_h, dev_nxt,
+                        )
+                        with _span("decode:fetch", timed=True) as sf:
+                            g, na = jax.device_get((greedy, n_acc))
+                    else:
+                        fn = self._paged_decode_fn(bound)
+                        tok, dev_nxt, dev_pos, cache = fn(
+                            self.params, dev_nxt, cache, dev_table, dev_pos,
+                            dev_mask,
+                        )
+                        with _span("decode:fetch", timed=True) as sf:
+                            g = np.asarray(tok)[:, None]
+                        na = np.zeros((num_slots,), np.int32)
+                    waited += sf.t1 - sf.t0
+                    if tracer is not None:
+                        sd.tags.update(slots=len(active_dec), bound=bound,
+                                       window=W)
+                t0d, now = sd.t0, sd.t1
                 decode_s += now - t0d
                 tp_event("verify" if use_spec else "decode", t0d, now,
                          num_slots * W)
@@ -1970,7 +2098,7 @@ class ServingEngine:
                         # full cache hit: the first token came from this
                         # decode boundary, not from a prefill launch
                         replay_first.discard(s)
-                        req._ttft_s = now - submit_s[req.request_id]  # type: ignore
+                        req._first_at = now     # type: ignore[attr-defined]
                     if spec:
                         prop = len(drafts.get(s, ()))
                         ledger.record(req.request_id, prop, a)
@@ -2003,6 +2131,8 @@ class ServingEngine:
             pages_sum += pool.num_in_use
             samples += 1
             slots.record_occupancy(step)
+            boundaries += 1
+            host_s += clock() - t_boundary - waited
             if not progressed and not prefilling and not decoding:
                 raise RuntimeError("paged serve loop stalled (admission deadlock)")
         if fault_hook is not None and hasattr(fault_hook, "release"):
@@ -2010,6 +2140,7 @@ class ServingEngine:
         jax.block_until_ready(cache["k_pages"])
         wall = clock() - t_start
         results = [finished[r.request_id] for r in requests]
+        gaps = _fill_itl(results)
         total_tokens = sum(len(r.tokens) for r in results)
         completed_n = sum(1 for r in results if r.status == "completed")
         in_goodput = sum(
@@ -2048,8 +2179,10 @@ class ServingEngine:
             decode_s=decode_s,
             spec_k=spec_k,
             spec_stats=ledger.stats() if ledger else {},
-            itl_p50_ms=percentile(itl_all, 50.0) * 1e3 if itl_all else 0.0,
-            itl_p99_ms=percentile(itl_all, 99.0) * 1e3 if itl_all else 0.0,
+            itl_p50_ms=percentile(gaps, 50.0) * 1e3 if gaps else 0.0,
+            itl_p99_ms=percentile(gaps, 99.0) * 1e3 if gaps else 0.0,
+            boundaries=boundaries,
+            host_s=host_s,
             tp=self.tp,
             kv_dtype=pool_dtype,
             kv_bytes_per_token=float(

@@ -11,6 +11,8 @@ runs, so these tests say nothing about results or times.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -66,6 +68,15 @@ def _compile_hlo(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _named_kernel(hlo: str, name: str) -> bool:
+    """The Mosaic kernel's instruction carries the kernel's own name, so a
+    trace names it whatever shape its result takes."""
+    return re.search(
+        rf'^\s*(ROOT )?%{name}(\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+        hlo, re.M,
+    ) is not None
+
+
 def _pool_args(one_chip, pool_dtype):
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     pools = [spec((NUM_PAGES, PAGE, KVH, D), pool_dtype)] * 2
@@ -97,6 +108,7 @@ def test_paged_attention_compiles_for_v5e(one_chip, pool):
         *scales,
     )
     assert "tpu_custom_call" in hlo
+    assert _named_kernel(hlo, "paged_attention")
 
 
 @pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
@@ -125,6 +137,7 @@ def test_varlen_prefill_compiles_for_v5e(one_chip, pool):
         *scales,
     )
     assert "tpu_custom_call" in hlo
+    assert _named_kernel(hlo, "varlen_prefill")
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
