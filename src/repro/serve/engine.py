@@ -265,6 +265,12 @@ class PagedStats:
     prefill_padded_tokens: int = 0  # packed-buffer slots spent on padding
     prefill_budget: int = 0     # packed-buffer tokens per boundary (0 = chunked)
     prefill_budget_stats: Dict[str, float] = field(default_factory=dict)
+    # (q block, key stage) pairs of the packed launches, summed: the live
+    # ones the varlen kernel iterates, and its whole nqb x (bound + nqb)
+    # grid (``kernels.varlen_prefill.work_items``); 0 on a backend that
+    # does not run that kernel
+    prefill_kv_live: int = 0
+    prefill_kv_rect: int = 0
     # -- prompt-token ledger: admitted tokens split exactly into computed
     # (prefill_tokens above), served from the prefix cache, and abandoned by
     # preemption before they were ever prefilled.  Invariant (asserted in
@@ -1085,6 +1091,7 @@ class ServingEngine:
         prefill_s = 0.0
         prefill_tokens = 0
         prefill_padded = 0
+        kv_live = kv_rect = 0
         prompt_admitted = 0
         saved_tokens = 0
         dropped_tokens = 0
@@ -1853,6 +1860,18 @@ class ServingEngine:
                         )
                         bound = bucket_pow2(max(ctx_pages, 1),
                                             cap=max_pages_per_seq)
+                        # the varlen kernel's work items, where it runs
+                        live = rect = 0
+                        if self.model.backend == "pallas":
+                            # lazy: pallas import cost
+                            from ..kernels.varlen_prefill import work_items
+
+                            live, rect = work_items(
+                                cu, lens_c, pos0_c, t_pack=t_pack,
+                                block=page_size, pages_bound=bound,
+                            )
+                        kv_live += live
+                        kv_rect += rect
                         fn = self._packed_prefill_fn(
                             t_pack, num_chunks, max_pages_per_seq, bound
                         )
@@ -1907,6 +1926,7 @@ class ServingEngine:
                                 tokens=real, padding=t_pack - real,
                                 chunks=len(spans), buffer=t_pack,
                                 budget=budget.tokens_per_step,
+                                live=live, rect=rect,
                             )
                 prefill_s += sp.t1 - sp.t0
                 if spans:
@@ -2167,6 +2187,8 @@ class ServingEngine:
             prefill_s=prefill_s,
             prefill_tokens=prefill_tokens,
             prefill_padded_tokens=prefill_padded,
+            prefill_kv_live=kv_live,
+            prefill_kv_rect=kv_rect,
             prefill_budget=t_pack if packed else 0,
             prefill_budget_stats=budget.stats() if budget else {},
             prompt_tokens_admitted=prompt_admitted,

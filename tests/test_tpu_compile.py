@@ -123,19 +123,46 @@ def test_spec_verify_compiles_for_v5e(one_chip, pool):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
-def test_varlen_prefill_compiles_for_v5e(one_chip, pool):
+def _compile_varlen(one_chip, pool, pages_bound, t=PACKED_T, chunks=CHUNKS,
+                    max_pages=MAX_PAGES) -> str:
     spec, pools, scales = _pool_args(one_chip, POOL_DTYPES[pool])
-    hlo = _compile_hlo(
-        _with_scales(varlen_prefill, 9, pages_bound=MAX_PAGES),
-        spec((PACKED_T, H, D), jnp.bfloat16),
-        spec((PACKED_T, KVH, D), jnp.bfloat16),
-        spec((PACKED_T, KVH, D), jnp.bfloat16),
+    return _compile_hlo(
+        _with_scales(varlen_prefill, 9, pages_bound=pages_bound),
+        spec((t, H, D), jnp.bfloat16),
+        spec((t, KVH, D), jnp.bfloat16),
+        spec((t, KVH, D), jnp.bfloat16),
         *pools,
-        spec((CHUNKS + 1,), jnp.int32), spec((CHUNKS,), jnp.int32),
-        spec((CHUNKS,), jnp.int32), spec((CHUNKS, MAX_PAGES), jnp.int32),
+        spec((chunks + 1,), jnp.int32), spec((chunks,), jnp.int32),
+        spec((chunks,), jnp.int32), spec((chunks, max_pages), jnp.int32),
         *scales,
     )
+
+
+@pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
+def test_varlen_prefill_compiles_for_v5e(one_chip, pool):
+    hlo = _compile_varlen(one_chip, pool, MAX_PAGES)
+    assert "tpu_custom_call" in hlo
+    assert _named_kernel(hlo, "varlen_prefill")
+
+
+@pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
+def test_varlen_prefill_one_context_page_compiles_for_v5e(one_chip, pool):
+    """The smallest context bound (a refill with no context): the per-block
+    item starts and the dynamic grid bound still lower, under the kernel's
+    name."""
+    hlo = _compile_varlen(one_chip, pool, 1)
+    assert "tpu_custom_call" in hlo
+    assert _named_kernel(hlo, "varlen_prefill")
+
+
+@pytest.mark.parametrize("t", [2048, 4096])
+@pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
+def test_varlen_prefill_long_context_compiles_for_v5e(one_chip, pool, t):
+    """Packed buffers of 2,048 and 4,096 tokens over 32K-token contexts
+    (2,048 pages a chunk, 16 chunks): what the kernel keeps in scalar
+    memory grows with the q blocks, not with the context bound, so it
+    still fits."""
+    hlo = _compile_varlen(one_chip, pool, 2048, t=t, chunks=16, max_pages=2048)
     assert "tpu_custom_call" in hlo
     assert _named_kernel(hlo, "varlen_prefill")
 

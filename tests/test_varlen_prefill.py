@@ -12,7 +12,8 @@ from repro.core.analysis import (
     prefill_saturation_summary,
 )
 from repro.core.tracing import Span, TraceLevel
-from repro.kernels import ops, ref
+from repro.kernels import kvquant, ops, ref
+from repro.kernels.varlen_prefill import _item_starts, work_items
 from repro.kernels.varlen_prefill import varlen_prefill as pallas_varlen
 from repro.models import build_model
 from repro.serve.engine import ServeRequest, ServingEngine
@@ -115,6 +116,63 @@ def test_varlen_pages_bound_exact():
     )
 
 
+# Packings for the work list: (chunks, T, pages_bound, kwargs, items).
+# ``items`` is counted by hand: per chunk of L real q blocks and P context
+# pages, L * min(P, bound) context items and L (L + 1) / 2 causal intra
+# items, plus one item for each q block that holds no real token.
+WORK_CASES = {
+    # one 5-token chunk in an 8-block buffer: 1 live block, 7 pad blocks
+    "short_chunk_in_large_buffer": ([(5, 0)], 64, None, {}, 1 + 7),
+    # 2 context pages under a bound of 4: L=2, 2*2 + 3
+    "context_below_bound": ([(16, 2)], 16, 4, {}, 4 + 3),
+    # L = 1, 2, 1 with P = 0, 2, 0; one pad block
+    "several_chunks_one_with_context":
+        ([(5, 0), (11, 2), (3, 0)], 40, None, {}, 1 + (4 + 3) + 1 + 1),
+    # no real token anywhere: one item per block
+    "all_pad": ([(0, 0)], 16, None, {}, 2),
+    # L = 2, 1 with P = 2, 1
+    "window_with_softcap":
+        ([(13, 2), (6, 1)], 32, None, dict(window=5, softcap=11.0),
+         (4 + 3) + (1 + 1) + 1),
+    # L = 2, 1 with P = 2, 1, context pages from an int8 pool
+    "quantized_pool": ([(9, 2), (4, 1)], 32, None, dict(quantized=True),
+                       (4 + 3) + (1 + 1) + 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WORK_CASES))
+def test_pallas_varlen_work_list(case):
+    """The work-list kernel against the oracle on packings whose grid is
+    mostly dead: pad rows come back exactly zero, and the kernel iterates
+    exactly the hand-counted live items, as the host helper counts them."""
+    chunks, T, bound, kw, items = WORK_CASES[case]
+    kw = dict(kw)
+    args = _pack(chunks, T)
+    q, k, v, kp, vp, cu, lens, pos0, tables = args
+    oracle_pools, scales = (kp, vp), {}
+    if kw.pop("quantized", False):
+        (kq, ks), (vq, vs) = (kvquant.quantize(x, jnp.int8) for x in (kp, vp))
+        oracle_pools = kvquant.dequantize(kq, ks), kvquant.dequantize(vq, vs)
+        kp, vp, scales = kq, vq, dict(k_scales=ks, v_scales=vs)
+    want = ref.varlen_prefill(q, k, v, *oracle_pools, cu, lens, pos0, tables, **kw)
+    got = np.asarray(pallas_varlen(q, k, v, kp, vp, cu, lens, pos0, tables,
+                                   pages_bound=bound, **kw, **scales))
+    np.testing.assert_allclose(np.asarray(want, np.float32), got,
+                               **_tol(jnp.float32))
+    real = np.zeros(T, bool)
+    for c, n in enumerate(np.asarray(lens)):
+        real[int(cu[c]) : int(cu[c]) + n] = True
+    assert np.all(got[~real] == 0.0)
+
+    ctx_bound = max(min(bound or tables.shape[1], tables.shape[1]), 1)
+    _, count, _, _ = _item_starts(cu, lens, pos0, nqb=T // PAGE, block=PAGE,
+                                  ctx_bound=ctx_bound)
+    live, rect = work_items(cu, lens, pos0, t_pack=T, block=PAGE,
+                            pages_bound=ctx_bound)
+    assert live == int(count) == items
+    assert rect == (T // PAGE) * (ctx_bound + T // PAGE)
+
+
 def test_varlen_jnp_non_aligned_chunk_boundaries():
     """A page-multiple buffer with NON-page-aligned chunk boundaries must
     take the exact per-token path (a block straddling two chunks would
@@ -202,6 +260,49 @@ def test_serve_paged_packed_matches_chunked():
     assert packed.prefill_launches < packed.prefill_chunks + len(prompts)
     assert packed.prefill_launches <= chunked.prefill_launches
     assert packed.prefill_budget_stats["granted_tokens"] == packed.prefill_tokens
+
+
+class _Tags:
+    """A tracer that keeps each event's tags by name."""
+
+    def __init__(self):
+        self.tags = []
+
+    def event(self, name, begin, end, **tags):
+        self.tags.append((name, tags))
+
+
+@pytest.mark.parametrize("backend", ["pallas", "flash"])
+def test_serve_paged_counts_live_kernel_items(backend):
+    """``prefill_kv_live`` / ``prefill_kv_rect`` sum each packed launch's
+    live (q block, key stage) items and its whole grid, counted by hand:
+    pages of 4 tokens, an 8-token buffer (2 q blocks).
+    Launch 1: request 0's first 8 tokens, no context (bound 1): 1 + 2
+    intra items of 2 x (1 + 2).  Launch 2: request 0's last 2 tokens over
+    2 context pages (bound 2), 2 + 1 items, and request 1's 3 tokens, 1
+    item, of 2 x (2 + 2).  The launches' ``prefill:packed`` tags add up to
+    the same sums.  A backend that does not run the varlen kernel counts
+    nothing."""
+    cfg = get_config("glm4-9b", reduced=True)
+    model = build_model(cfg, backend=backend)
+    engine = ServingEngine(model, model.init(jax.random.PRNGKey(0)),
+                           max_batch=2, max_seq=32)
+    rng = np.random.default_rng(17)
+    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (10, 3)]
+    tracer = _Tags()
+    stats = engine.serve_paged(
+        [ServeRequest(request_id=i, prompt=p, max_new_tokens=2)
+         for i, p in enumerate(prompts)],
+        num_slots=2, page_size=4, prefill_mode="packed", prefill_budget=8,
+        tracer=tracer,
+    )
+    assert stats.prefill_launches == 2
+    want = (3 + 4, 6 + 8) if backend == "pallas" else (0, 0)
+    assert (stats.prefill_kv_live, stats.prefill_kv_rect) == want
+    launches = [tags for name, tags in tracer.tags if name == "prefill:packed"]
+    assert (sum(t["live"] for t in launches),
+            sum(t["rect"] for t in launches)) == want
 
 
 def test_serve_paged_packed_budget_caps_boundary_tokens():
