@@ -45,7 +45,8 @@ def readings(cell: files.Cell, seeds, rounds: int = 1, allow_cpu: bool = False,
                                      "reference's layer-by-layer weights")
         else:
             s.engine.params = W.make_params(s.dims, seed,
-                                            dtype=cell.config["dtype"])
+                                            dtype=cell.config["dtype"],
+                                            shardings=s.shardings)
         served = []
         for i in range(rounds):
             rd = bench.serve_round(s, cell, i, seed)

@@ -5,14 +5,15 @@ and q/k/v/o bytes of every prompt, from the served lengths
 
 The trace names the kernel only by its HLO instruction, a Mosaic
 ``custom-call`` whose result is the packed query block
-``bf16[kv_heads, budget * heads / kv_heads, head_dim]``."""
+``bf16[kv_heads, budget * heads / kv_heads, head_dim]``; under tensor
+parallelism each chip's kernel holds ``kv_heads / tp`` of the kv heads."""
 from chipbench import work
 
 
 def read(run):
     d = run.dims
     rows = int(run.cell.serve["serve"]["prefill_budget"]) * (d.heads // d.kv_heads)
-    shape = f"= bf16[{d.kv_heads},{rows},{d.head_dim}]"
+    shape = f"= bf16[{d.kv_heads // run.tp},{rows},{d.head_dim}]"
 
     def match(text):
         return "tpu_custom_call" in text and shape in text

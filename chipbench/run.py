@@ -17,6 +17,10 @@ requests is compared with the float32 reference (``check``).  The last
 line of standard output is the JSON result; everything else goes to
 standard error.  Without a TPU, or with fewer chips than the cell asks
 for, it exits 1 and prints no result.
+
+A cell over N chips runs one model over all of them with the program's
+own tensor parallelism: the engine under ``serve_rules(make_serve_mesh(N))``
+and every weight made in the sharding those rules give it.
 """
 from __future__ import annotations
 
@@ -89,6 +93,9 @@ class Run:
     peak: Dict[str, float]
     trace: Optional[tracemod.Reduction] = None
     traced_round: Optional[Round] = None
+    # the engine's tensor-parallel degree: each chip's kernels hold
+    # kv_heads // tp of the kv heads
+    tp: int = 1
 
 
 def parse_args(argv=None):
@@ -227,10 +234,29 @@ class Setup:
     info: Dict[str, Any]
     dims: W.Dims
     engine: Any
+    devices: List[Any]          # the cell's chips
+    shardings: Any              # the weights' shardings; None on one chip
     kw: Dict[str, Any]
     compile_times: List[float]
     cache_dir: Optional[str]
     marks: Dict[str, float]
+
+
+def tp_layout(model, mesh):
+    """The program's serving rules over ``mesh`` and the weights'
+    shardings under them."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.sharding.specs import param_pspecs, serve_rules
+
+    rules = serve_rules(mesh)
+    shardings = jax.tree.map(
+        lambda p: NamedSharding(rules.mesh, p),
+        param_pspecs(model.param_defs(), rules),
+        is_leaf=lambda x: isinstance(x, PartitionSpec),
+    )
+    return rules, shardings
 
 
 def build(cell: files.Cell, seed: int, allow_cpu: bool = False,
@@ -242,6 +268,7 @@ def build(cell: files.Cell, seed: int, allow_cpu: bool = False,
     info = device_info(jax, cell.chips, allow_cpu)
     marks = {"device": time.perf_counter() - T_PROCESS}
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_serve_mesh
     from repro.models import build_model
     from repro.serve.engine import ServingEngine
 
@@ -256,9 +283,12 @@ def build(cell: files.Cell, seed: int, allow_cpu: bool = False,
     )
     d = W.dims(cell.config)
     model = build_model(program_config(cell, d))
+    rules, shardings = (tp_layout(model, make_serve_mesh(cell.chips))
+                        if cell.chips > 1 else (None, None))
     want = jax.tree.map(lambda s: (s.shape, str(s.dtype)),
                         model.param_specs(cell.config["dtype"]))
-    params = W.make_params(d, seed, dtype=cell.config["dtype"])
+    params = W.make_params(d, seed, dtype=cell.config["dtype"],
+                           shardings=shardings)
     got = jax.tree.map(lambda a: (a.shape, str(a.dtype)), params)
     if got != want:
         raise ValueError("the benchmark's weight layout no longer matches the "
@@ -268,12 +298,13 @@ def build(cell: files.Cell, seed: int, allow_cpu: bool = False,
     engine = ServingEngine(
         model, params, max_batch=int(cell.serve["slots"]),
         max_seq=traffic.max_len(cell.traffic), cache_dtype=cell.config["dtype"],
-        page_size=int(cell.serve["serve"]["page_size"]),
+        page_size=int(cell.serve["serve"]["page_size"]), rules=rules,
     )
     if fault is not None:
         fault(engine)
-    return Setup(jax, info, d, engine, serve_kwargs(cell), compile_times,
-                 cache_dir, marks)
+    devices = list(rules.mesh.devices.flat) if rules else jax.devices()[:1]
+    return Setup(jax, info, d, engine, devices, shardings, serve_kwargs(cell),
+                 compile_times, cache_dir, marks)
 
 
 def warm_up(s: Setup, cell: files.Cell) -> int:
@@ -333,7 +364,8 @@ def measure(args, cell: files.Cell, allow_cpu: bool,
     n_sets = warm_up(s, cell)
     variants = s.engine.compile_stats()
     setup_s = time.perf_counter() - T_PROCESS
-    say(f"device {info['platform']} {info['kind']} x{info['count']}; set-up "
+    say(f"device {info['platform']} {info['kind']} x{info['count']} "
+        f"(tp {s.engine.tp}); set-up "
         f"{setup_s:.3f} s (device ready {s.marks['device']:.3f} s, weights "
         f"{s.marks['weights']:.3f} s, then {n_sets} warm-up sets), "
         f"{len(s.compile_times)} backend compiles, cache {s.cache_dir}")
@@ -372,9 +404,10 @@ def measure(args, cell: files.Cell, allow_cpu: bool,
         f"new engine variants {new_variants or 'none'}; {len(gc_pauses)} "
         f"garbage collections, longest {max(gc_pauses, default=0.0):.3f} s")
 
-    dev = jax.devices()[0]
-    mem = dev.memory_stats() or {}
-    info["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
+    each = [int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for dev in s.devices]
+    info["memory_peak_bytes"] = max(each)
+    info["memory_peak_bytes_each"] = each
 
     results = [r for rd in rounds for r in rd.stats.results]
     failed = sum(
@@ -385,11 +418,14 @@ def measure(args, cell: files.Cell, allow_cpu: bool,
               for rd in rounds for (p, n), r in zip(rd.requests, rd.stats.results)
               if r.status == "completed" and len(r.tokens) == n]
 
-    run = Run(cell, d, int(cell.serve["slots"]), rounds, window_s, {})
+    run = Run(cell, d, int(cell.serve["slots"]), rounds, window_s, {},
+              tp=s.engine.tp)
     reduction = None
     if trace_dir is not None:
         try:
-            reduction = tracemod.reduce(tracemod.load(tracemod.find_xplane(trace_dir)))
+            reduction = tracemod.reduce(tracemod.load(
+                tracemod.find_xplane(trace_dir),
+                devices=[dev.id for dev in s.devices]))
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
         info["busy_s"] = reduction.busy_s

@@ -4,9 +4,12 @@ time and idle gaps attributed to what the host was doing.
 The window is the host span the benchmark opened around the traced work
 (``WINDOW``).  Busy time is the union of the intervals in which an
 operation ran on a device, clipped to the window and averaged over the
-devices.  An idle gap is a stretch of the window with no device operation;
-it is labelled by the innermost host event on the window's thread that
-overlaps it (the benchmark's own spans, or JAX's dispatch events).
+devices.  An idle gap is a stretch of the window with no operation on the
+first device; it is labelled by the innermost host event on the window's
+thread that overlaps it (the benchmark's own spans, or JAX's dispatch
+events).  Per-operation time is summed over the devices: under tensor
+parallelism each chip runs its share of every operation, so an
+operation's time is that of all its shares.
 
 On a TPU the operations' names are their HLO instructions, and a ``while``
 or ``call`` spans the operations of its body: only operations that hold no
@@ -19,11 +22,12 @@ import glob
 import os
 import re
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 WINDOW = "chipbench:traced"
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+_ORDINAL = re.compile("^" + re.escape(DEVICE_PREFIX) + r"(\d+)")
 
 
 @dataclass
@@ -105,15 +109,25 @@ def _event(ev) -> Event:
                  " ".join([ev.name] + stats))
 
 
-def load(path: str, window: str = WINDOW) -> Trace:
-    """Device operations and the window's host thread from ``path``."""
+def _ordinal(plane_name: str) -> int:
+    m = _ORDINAL.match(plane_name)
+    return int(m.group(1)) if m else -1
+
+
+def load(path: str, window: str = WINDOW,
+         devices: Optional[Sequence[int]] = None) -> Trace:
+    """Device operations and the window's host thread from ``path``; with
+    ``devices``, the operations of the devices of those ids only (a plane
+    is named by its device's id), in the order of their ids."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     tr = Trace()
     host_lines = []
-    for plane in data.planes:
+    for plane in sorted(data.planes, key=lambda p: _ordinal(p.name)):
         if plane.name.startswith(DEVICE_PREFIX):
+            if devices is not None and _ordinal(plane.name) not in devices:
+                continue
             for line in plane.lines:
                 if line.name == OPS_LINE:
                     tr.devices[plane.name] = [_event(e) for e in line.events]

@@ -100,35 +100,66 @@ def layer(key, l, d: Dims, dtype=jnp.bfloat16) -> Dict[str, Any]:
     }
 
 
-def table(key, name: str, rows: int, cols: int, dtype=jnp.bfloat16):
-    """A (rows, cols) table drawn in ``TABLE_BLOCKS`` row blocks."""
-    if rows % TABLE_BLOCKS:
+def table(key, name: str, rows: int, cols: int, dtype=jnp.bfloat16,
+          shards: int = 1):
+    """A (rows, cols) table drawn in ``TABLE_BLOCKS`` row blocks.
+
+    The rows lie in ``shards`` equal parts, one a device: each step of
+    the loop draws one block of every part side by side, so each device
+    draws only blocks of its own rows.  Block ``b`` is the same draw
+    whatever ``shards`` is."""
+    if rows % TABLE_BLOCKS or TABLE_BLOCKS % shards:
         raise ValueError(f"{name}: {rows} rows do not split into "
-                         f"{TABLE_BLOCKS} blocks")
-    blocks = jax.lax.map(
-        lambda b: _leaf(key, name, b, (rows // TABLE_BLOCKS, cols), STD_IN,
-                        dtype),
-        jnp.arange(TABLE_BLOCKS),
+                         f"{TABLE_BLOCKS} blocks over {shards} shards")
+    shape = (rows // TABLE_BLOCKS, cols)
+    per = TABLE_BLOCKS // shards
+    # (per, shards, *shape): step j draws blocks i * per + j, i < shards
+    steps = jax.lax.map(
+        lambda j: jax.vmap(lambda i: _leaf(key, name, i * per + j, shape,
+                                           STD_IN, dtype))(jnp.arange(shards)),
+        jnp.arange(per),
     )
-    return blocks.reshape(rows, cols)
+    return steps.swapaxes(0, 1).reshape(rows, cols)
 
 
 def final_norm(key, d: Dims, dtype=jnp.bfloat16):
     return _leaf(key, "final_norm", 0, (d.d_model,), STD_NORM, dtype)
 
 
-def make_params(d: Dims, seed: int, dtype=jnp.bfloat16):
-    """Every weight of the model, stacked as the program holds them, made
-    on the device by one jitted call."""
+def _vocab_shards(sharding, shape, axis: int) -> int:
+    """Parts the vocabulary axis ``axis`` of a table lies in."""
+    return shape[axis] // sharding.shard_shape(shape)[axis]
+
+
+def params_fn(d: Dims, dtype=jnp.bfloat16, shardings=None):
+    """The jitted call that makes every weight of the model from a root
+    key, stacked as the program holds them.
+
+    ``shardings``, a tree of shardings like the weights' (the program's
+    tensor-parallel layout), makes every leaf in its sharding: each device
+    draws only its share, the float32 draws included, and the values are
+    those of the unsharded call (``jax_threefry_partitionable``)."""
+    V, D = d.vocab, d.d_model
+    embed_shards = head_shards = 1
+    if shardings is not None:
+        embed_shards = _vocab_shards(shardings["embed"], (V, D), 0)
+        head_shards = _vocab_shards(shardings["lm_head"], (D, V), 1)
 
     def build(key):
         return {
-            "embed": table(key, "embed", d.vocab, d.d_model, dtype),
+            "embed": table(key, "embed", V, D, dtype, embed_shards),
             "blocks": jax.lax.map(lambda l: layer(key, l, d, dtype),
                                   jnp.arange(d.layers)),
             "final_norm": final_norm(key, d, dtype),
             # stored (vocab, d_model) like the embedding, served transposed
-            "lm_head": table(key, "lm_head", d.vocab, d.d_model, dtype).T,
+            "lm_head": table(key, "lm_head", V, D, dtype, head_shards).T,
         }
 
-    return jax.jit(build)(root_key(seed))
+    # None places every leaf as a plain jit does
+    return jax.jit(build, out_shardings=shardings)
+
+
+def make_params(d: Dims, seed: int, dtype=jnp.bfloat16, shardings=None):
+    """Every weight of the model made on the device from ``seed`` by one
+    jitted call (``params_fn``)."""
+    return params_fn(d, dtype, shardings)(root_key(seed))

@@ -103,7 +103,12 @@ def kernel_roofline(run, kernel, match) -> "float | None":
     """Share (%) of its roofline that a kernel reached in the traced round:
     the least time the chip needs for the round's ``kernel`` work over
     the summed device time of the trace's operations that ``match``
-    accepts.  None where the trace holds no such operation."""
+    accepts.  None where the trace holds no such operation.
+
+    Over ``tp`` chips each runs its share of the work in about the same
+    time ``t``, and the trace sums the time over every chip: the whole
+    work over (one chip's peak x ``tp`` x ``t``) is then each chip's share
+    of its own roofline, with no count of chips here."""
     if run.trace is None or run.traced_round is None or not run.peak:
         return None
     secs = run.trace.seconds_matching(match)

@@ -98,6 +98,21 @@ def test_stacked_weights_equal_the_references_layers(dtype):
     assert bool((table(key, "lm_head", 256, 64, dtype).T == p["lm_head"]).all())
 
 
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_table_blocks_are_the_same_draw_over_any_shards(shards):
+    import jax
+    import jax.numpy as jnp
+
+    key = W.root_key(2**40 + 5)
+    table = jax.jit(W.table, static_argnums=(1, 2, 3, 4, 5))
+    got = table(key, "embed", 256, 64, jnp.bfloat16, shards)
+    # block b, drawn alone, is rows [16 b, 16 b + 16) whatever the shards
+    want = jnp.concatenate([W._leaf(key, "embed", b, (16, 64), W.STD_IN,
+                                    jnp.bfloat16)
+                            for b in range(W.TABLE_BLOCKS)])
+    assert bool((got == want).all())
+
+
 def test_weights_depend_on_the_seed():
     a = W.make_params(TINY, 1)
     b = W.make_params(TINY, 2)
